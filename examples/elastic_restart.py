@@ -26,7 +26,8 @@ from repro.train.optimizer import OptConfig, adamw_init, make_train_step
 
 
 def mesh_of(shape):
-    return meshes.make_mesh(shape, ("data", "model"))
+    return jax.make_mesh(shape, ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def place(params, specs, mesh):

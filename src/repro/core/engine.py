@@ -394,9 +394,9 @@ class Engine:
 
             return jax.lax.scan(body, models, (X, Y, mask))
 
-        return dist_meshes.shard_map(
-            epoch, mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+        return jax.shard_map(
+            epoch, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
         )
 
     def _sharded_epoch_fn(self, mesh, path, data_axes, model_axis):
@@ -539,6 +539,17 @@ class Engine:
             self.last_sharded_path = sharded_path
             models = self._place_models(models, mesh, sharded_path[2])
         return fn(models, jnp.asarray(pages))
+
+    def lower_chunk(self, models, pages, layout, use_kernel: bool | None = None):
+        """The fused chunk program ``run_chunk`` dispatches for these
+        arguments, lowered: ``.compile().as_text()`` shows the kernels in it
+        (``tpu_custom_call``)."""
+        from repro.kernels.strider import ops as strider_ops
+
+        if use_kernel is None:
+            use_kernel = strider_ops.default_use_kernel()
+        fn, _ = self._chunk_fn(layout, bool(use_kernel), self._active_mesh())
+        return fn.lower(models, jnp.asarray(pages))
 
     def converged(self, models, merged) -> bool:
         return bool(self._conv(models, merged, self.metas))

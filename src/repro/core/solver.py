@@ -77,13 +77,13 @@ def _device_sync(tree):
     return jax.block_until_ready(tree)
 
 
-def _decode_chunk(pages_np, heap, mode):
+def _decode_chunk(pages_np, heap, mode, use_kernel=None):
     layout = heap.layout
     if mode == "dana":
         from repro.kernels.strider import ops as strider_ops
 
         feats, labels, mask = strider_ops.decode_pages(
-            jnp.asarray(pages_np), layout
+            jnp.asarray(pages_np), layout, use_kernel
         )
         t = feats.shape[0] * feats.shape[1]
         return (
@@ -119,6 +119,7 @@ def train_units(
     seed: int = 0,
     mesh: jax.sharding.Mesh | None = None,
     shard_model: bool = False,
+    use_kernel: bool | None = None,
 ):
     """Generator form of the pipelined executor: yields once per device chunk
     *dispatch* — the unit the concurrent query executor (``db/executor.py``)
@@ -131,7 +132,13 @@ def train_units(
     trained model is byte-identical whether the scan runs alone or
     interleaved with other queries. Timing fields measure this query's wall
     clock; under interleaving, co-scheduled work shows up as compute time
-    (results never change, attribution does)."""
+    (results never change, attribution does).
+
+    ``use_kernel`` picks the device datapath: None runs the Pallas strider
+    and GLM kernels on TPU and their jnp references elsewhere; False runs
+    the references (strider ``ref.py`` decode, vmapped hDFG update) on any
+    backend — the plain float32 path the kernels are checked against; True
+    forces the strider kernel (interpret mode on CPU)."""
     t_start = time.perf_counter()
     if engine is not None and shard_model and not engine.shard_model:
         # silently training replicated when the caller asked for a
@@ -141,7 +148,8 @@ def train_units(
             "pass make_engine(..., shard_model=True)"
         )
     engine = engine or make_engine(
-        g, part, merge_coef=merge_coef, mesh=mesh, shard_model=shard_model
+        g, part, merge_coef=merge_coef, mesh=mesh, shard_model=shard_model,
+        use_fused_kernel=use_kernel is not False,
     )
     pool = pool or BufferPool(
         pool_bytes=MAX_RESIDENT_PAGES * heap.layout.page_bytes,
@@ -197,12 +205,12 @@ def train_units(
                         # one fused XLA program: strider decode + batch
                         # reshape + epoch scan; no intermediate sync
                         models, gnorms = engine.run_chunk(
-                            models, pages_np, heap.layout
+                            models, pages_np, heap.layout, use_kernel
                         )
                     else:
                         t1 = time.perf_counter()
                         feats, labels, mask = _decode_chunk(
-                            pages_np, heap, mode
+                            pages_np, heap, mode, use_kernel
                         )
                         decode_epoch += time.perf_counter() - t1
                         X, Y, M = _batches(feats, labels, mask, coef)
@@ -220,7 +228,8 @@ def train_units(
                 epochs_run = epoch + 1
                 if g.convergence_id is not None:
                     if _check_convergence(
-                        engine, models, heap, pool, mode, coef, conv_cache
+                        engine, models, heap, pool, mode, coef, conv_cache,
+                        use_kernel,
                     ):
                         converged = True
                         break
@@ -264,6 +273,7 @@ def train(
     mesh: jax.sharding.Mesh | None = None,
     shard_model: bool = False,
     pipelined: bool = True,
+    use_kernel: bool | None = None,
 ) -> TrainResult:
     """``mesh`` (or an enclosing ``meshes.use_mesh``) turns on the engine's
     sharded epoch mode: the decoded tuple stream is split over the mesh's
@@ -276,12 +286,13 @@ def train(
     ``pipelined=True`` (default) drains the ``train_units`` generator — the
     double-buffered executor; ``pipelined=False`` keeps the fully
     synchronous per-chunk loop (the ablation both tests and benchmarks
-    compare against)."""
+    compare against). ``use_kernel`` is as in ``train_units``."""
     if pipelined and heap.n_pages > 0:
         gen = train_units(
             g, part, heap, pool=pool, mode=mode, engine=engine,
             max_epochs=max_epochs, merge_coef=merge_coef, models=models,
             seed=seed, mesh=mesh, shard_model=shard_model,
+            use_kernel=use_kernel,
         )
         while True:
             try:
@@ -297,7 +308,8 @@ def train(
             "pass make_engine(..., shard_model=True)"
         )
     engine = engine or make_engine(
-        g, part, merge_coef=merge_coef, mesh=mesh, shard_model=shard_model
+        g, part, merge_coef=merge_coef, mesh=mesh, shard_model=shard_model,
+        use_fused_kernel=use_kernel is not False,
     )
     pool = pool or BufferPool(
         pool_bytes=MAX_RESIDENT_PAGES * heap.layout.page_bytes,
@@ -333,7 +345,9 @@ def train(
                 t0 = time.perf_counter()
                 pages_np = pool.fetch_batch(heap, chunk_ids)
                 t1 = time.perf_counter()
-                feats, labels, mask = _decode_chunk(pages_np, heap, mode)
+                feats, labels, mask = _decode_chunk(
+                    pages_np, heap, mode, use_kernel
+                )
                 feats.block_until_ready()
                 t2 = time.perf_counter()
                 X, Y, M = _batches(feats, labels, mask, coef)
@@ -353,7 +367,8 @@ def train(
                 # convergence is evaluated once per epoch (paper §4.4) on
                 # the cached first-chunk batch
                 if _check_convergence(
-                    engine, models, heap, pool, mode, coef, conv_cache
+                    engine, models, heap, pool, mode, coef, conv_cache,
+                    use_kernel,
                 ):
                     converged = True
                     break
@@ -375,7 +390,7 @@ def train(
     )
 
 
-def _convergence_batch(engine, heap, pool, mode, coef, cache):
+def _convergence_batch(engine, heap, pool, mode, coef, cache, use_kernel):
     """Decode the first-chunk convergence batch once per train() call; every
     epoch's terminator check reuses the cached device arrays instead of
     refetching and re-decoding pages."""
@@ -383,15 +398,17 @@ def _convergence_batch(engine, heap, pool, mode, coef, cache):
     if batch is None:
         ids = np.arange(min(heap.n_pages, 4))
         pages_np = pool.fetch_batch(heap, ids)
-        feats, labels, mask = _decode_chunk(pages_np, heap, mode)
+        feats, labels, mask = _decode_chunk(pages_np, heap, mode, use_kernel)
         X, Y, M = _batches(feats, labels, mask, coef)
         batch = cache["batch"] = (X[0], Y[0], M[0])
     return batch
 
 
-def _check_convergence(engine, models, heap, pool, mode, coef, cache) -> bool:
+def _check_convergence(engine, models, heap, pool, mode, coef, cache,
+                       use_kernel=None) -> bool:
     """Evaluate the terminator on a fresh merged value from the first batch."""
-    x0, y0, m0 = _convergence_batch(engine, heap, pool, mode, coef, cache)
+    x0, y0, m0 = _convergence_batch(engine, heap, pool, mode, coef, cache,
+                                    use_kernel)
     _, merged = engine.batch_step(models, x0, y0, m0)
     return engine.converged(models, merged)
 

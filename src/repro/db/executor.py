@@ -342,12 +342,14 @@ class QueryExecutor:
                 artifact["hdfg"], artifact["partition"], heap,
                 pool=self.pool, mode=kw.get("mode", "dana"),
                 max_epochs=kw.get("max_epochs"), seed=kw.get("seed", 0),
+                use_kernel=kw.get("use_kernel", self.use_kernel),
             )
         else:
             gen = solver.train_units(
                 artifact["hdfg"], artifact["partition"], heap,
                 pool=self.pool, mode=kw.get("mode", "dana"),
                 max_epochs=kw.get("max_epochs"), seed=kw.get("seed", 0),
+                use_kernel=kw.get("use_kernel", self.use_kernel),
             )
             res = None
             while res is None:

@@ -608,6 +608,7 @@ def execute(
             max_epochs=max_epochs,
             seed=seed,
             pipelined=pipelined,
+            use_kernel=use_kernel,
         )
         artifact["model"] = res.models
         catalog.register_udf(stmt.udf, artifact)

@@ -161,12 +161,15 @@ def _scoring_model(artifact: dict, udf: str) -> np.ndarray:
 
 
 def _build_glm_chunk_fn(layout, plan, family, model, where, where_pos,
-                        use_kernel, aggregates=None, agg_pos=None):
+                        use_kernel, aggregates=None, agg_pos=None,
+                        proj_pos=()):
     """One fused device program per chunk: projected strider decode + WHERE
     keep-mask (the whole predicate tree evaluates traced) + model scoring.
 
-    Row mode returns (preds, keep, feats, labels) device arrays flattened
-    over tuples. Aggregate mode returns only (partial_sums, kept_count) —
+    Row mode returns (preds, keep, columns) device arrays flattened over
+    tuples; ``columns`` holds only the SELECT list (``proj_pos``: plan
+    positions, None for the label), not every decoded model column.
+    Aggregate mode returns only (partial_sums, kept_count) —
     one f32 scalar per aggregate plus a count, reduced on device; XLA
     dead-code-eliminates the scoring math when no aggregate reads
     ``prediction``. Nothing syncs until the caller joins.
@@ -225,7 +228,10 @@ def _build_glm_chunk_fn(layout, plan, family, model, where, where_pos,
                     jnp.sum(jnp.where(keep, val.astype(jnp.float32), 0.0))
                 )
             return jnp.stack(sums), jnp.sum(keep.astype(jnp.int32))
-        return preds, keep, f2, lab
+        cols = [lab if pos is None else f2[:, pos] for pos in proj_pos]
+        cols = (jnp.stack(cols, axis=1) if cols
+                else jnp.zeros((p * t, 0), jnp.float32))
+        return preds, keep, cols
 
     return run
 
@@ -384,6 +390,8 @@ class PredictScan:
         self.run_chunk = _build_glm_chunk_fn(
             layout, plan, family, model, stmt.where, where_pos, use_kernel,
             aggregates=stmt.aggregates, agg_pos=agg_pos,
+            proj_pos=[None if idx is None else plan.columns.index(idx)
+                      for idx in proj_idx],
         )
         self.page_chunks = [
             np.arange(s, min(s + self.chunk, self.heap.n_pages))
@@ -395,7 +403,7 @@ class PredictScan:
         """Collected chunk outputs (post-sync) -> QueryResult."""
         from repro.db import query as q
 
-        stmt, heap, plan = self.stmt, self.heap, self.plan
+        stmt, heap = self.stmt, self.heap
         if stmt.aggregates is not None:
             values, count = combine_aggregates(stmt.aggregates, outs)
             return q.QueryResult(
@@ -419,23 +427,13 @@ class PredictScan:
         if outs:
             preds = np.concatenate([np.asarray(o[0]) for o in outs])
             keep = np.concatenate([np.asarray(o[1]) for o in outs])
-            f2 = np.concatenate([np.asarray(o[2]) for o in outs])
-            lab = np.concatenate([np.asarray(o[3]) for o in outs])
+            cols = np.concatenate([np.asarray(o[2]) for o in outs])
         else:
             preds = np.zeros(0, np.float32)
             keep = np.zeros(0, bool)
-            f2 = np.zeros((0, plan.n_columns), np.float32)
-            lab = np.zeros(0, np.float32)
-        preds, f2, lab = preds[keep], f2[keep], lab[keep]
+            cols = np.zeros((0, len(self.proj_idx)), np.float32)
+        preds, result_feats = preds[keep], cols[keep].astype(np.float32)
         n_kept = int(keep.sum())
-
-        cols = []
-        for idx in self.proj_idx:
-            cols.append(lab if idx is None else f2[:, plan.columns.index(idx)])
-        result_feats = (
-            np.stack(cols, axis=1).astype(np.float32)
-            if cols else np.zeros((n_kept, 0), np.float32)
-        )
         schema = tuple(self.proj_names) + ("prediction",)
         result_layout = PageLayout(
             n_features=len(self.proj_names), page_bytes=self.layout.page_bytes,

@@ -29,10 +29,6 @@ physical device mesh:
 Shardings resolved by ``shard_act`` are captured at trace time: enter
 ``use_mesh`` *before* tracing/jitting (train.py, dryrun.py and the engine's
 per-mesh jit cache all do).
-
-A small compat layer papers over jax versions that predate
-``jax.sharding.AxisType`` / ``jax.make_mesh(..., axis_types=)`` /
-``AbstractMesh(sizes, names)``; it is a no-op on newer jax.
 """
 from __future__ import annotations
 
@@ -43,99 +39,8 @@ import threading
 import jax
 from jax.sharding import NamedSharding, PartitionSpec
 
-try:  # public since jax 0.5.x; same class lives in _src.mesh before that
-    from jax.sharding import AbstractMesh as _AbstractMesh
-except ImportError:  # pragma: no cover
-    from jax._src.mesh import AbstractMesh as _AbstractMesh
-
 Mesh = jax.sharding.Mesh
-
-
-# ---------------------------------------------------------------------------
-# jax version compat
-# ---------------------------------------------------------------------------
-def _install_jax_compat():
-    """Backfill the newer mesh API names used throughout the repo (tests
-    included, which call ``jax.make_mesh(..., axis_types=)`` and
-    ``jax.sharding.AbstractMesh(sizes, names)`` directly — hence the patch
-    must live on the jax namespace, not just on this module) onto older jax
-    releases. Idempotent; no-op when jax already provides them. The shimmed
-    ``make_mesh`` accepts only ``AxisType.Auto`` (old jax has no other
-    semantics) and raises rather than silently downgrading anything else."""
-    shd = jax.sharding
-    if not hasattr(shd, "AxisType"):
-        from jax._src.mesh import AxisTypes  # Auto / User / Collective
-
-        shd.AxisType = AxisTypes
-
-    import inspect
-
-    params = inspect.signature(jax.make_mesh).parameters
-    if "axis_types" not in params and not getattr(jax.make_mesh, "_compat", False):
-        _orig_make_mesh = jax.make_mesh
-
-        def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types=None):
-            if axis_types is not None and any(
-                t != shd.AxisType.Auto for t in axis_types
-            ):
-                raise NotImplementedError(
-                    f"axis_types {axis_types} need a jax release with "
-                    "explicit-axis support; this version only does Auto"
-                )
-            return _orig_make_mesh(axis_shapes, axis_names, devices=devices)
-
-        make_mesh._compat = True
-        jax.make_mesh = make_mesh
-
-    am_params = list(inspect.signature(_AbstractMesh.__init__).parameters)
-    if "shape_tuple" in am_params:  # old ctor: AbstractMesh(((name, size), ...))
-
-        class AbstractMeshCompat(_AbstractMesh):
-            """Old-jax AbstractMesh accepting the new (sizes, names) ctor.
-            A real subclass so isinstance checks against either name work."""
-
-            def __init__(self, *args, **kwargs):
-                if len(args) == 2 and not kwargs:  # new-style (sizes, names)
-                    sizes, names = args
-                    super().__init__(tuple(zip(names, sizes)))
-                else:
-                    super().__init__(*args, **kwargs)
-
-        shd.AbstractMesh = AbstractMeshCompat
-
-
-_install_jax_compat()
-
-
-def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """Version-agnostic ``jax.make_mesh`` with Auto axis types.
-    ``_install_jax_compat`` already ran, so ``axis_types`` is accepted
-    everywhere (natively or via the shim)."""
-    return jax.make_mesh(
-        axis_shapes, axis_names, devices=devices,
-        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-    )
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_rep: bool = False):
-    """Version-agnostic ``shard_map``: ``jax.shard_map`` on releases that
-    have it (the experimental alias was removed after its promotion),
-    ``jax.experimental.shard_map`` on the supported floor. Newer jax renamed
-    ``check_rep`` to ``check_vma``; both spellings are forwarded to whichever
-    the installed version takes."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:  # pragma: no cover - exercised on the 0.4.x floor
-        from jax.experimental.shard_map import shard_map as fn
-
-    import inspect
-
-    params = inspect.signature(fn).parameters
-    kw = {}
-    if "check_rep" in params:
-        kw["check_rep"] = check_rep
-    elif "check_vma" in params:
-        kw["check_vma"] = check_rep
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+_AUTO = jax.sharding.AxisType.Auto
 
 
 # ---------------------------------------------------------------------------
@@ -147,14 +52,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     across the inter-pod (DCN) boundary."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Degenerate mesh over whatever devices exist (tests, examples)."""
     n = jax.device_count()
     mp = max(1, min(model_parallel, n))
-    return make_mesh((n // mp, mp), ("data", "model"))
+    return jax.make_mesh((n // mp, mp), ("data", "model"),
+                         axis_types=(_AUTO, _AUTO))
 
 
 # ---------------------------------------------------------------------------
@@ -427,8 +333,7 @@ def mesh_data_axes(mesh) -> tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if sizes.get(a, 1) > 1)
 
 
-# the compat-shimmed name (a subclass of the real class on old jax), so
-# meshes.AbstractMesh(sizes, names) works on every supported version
+# device-free meshes for the resolver: meshes.AbstractMesh(sizes, names)
 AbstractMesh = jax.sharding.AbstractMesh
 
 __all__ = [
@@ -443,7 +348,6 @@ __all__ = [
     "current_mesh",
     "fallbacks",
     "make_host_mesh",
-    "make_mesh",
     "make_production_mesh",
     "mesh_axis_size",
     "mesh_data_axes",

@@ -20,7 +20,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.engine.ref import glm_act, glm_error
+from repro.kernels.engine.ref import HIGHEST, glm_act, glm_error
+
+
+def _hypothesis(x, w):
+    """z = w·Xᵀ as a lane-dense (1, TB) row: both MXU dots stay 2-D, which
+    is what the TPU compiler accepts (a 1-D contraction over the row axis
+    is refused)."""
+    return jax.lax.dot_general(
+        w, x, (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
 
 
 def _glm_kernel(x_ref, y_ref, w_ref, mask_ref, out_ref, *, act: str):
@@ -29,15 +39,12 @@ def _glm_kernel(x_ref, y_ref, w_ref, mask_ref, out_ref, *, act: str):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     x = x_ref[...]  # (TB, D) f32
-    w = w_ref[...]  # (1, D)  f32
-    z = jax.lax.dot_general(
-        x, w[0, :], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (TB,)
-    e = glm_error(z, y_ref[0, :], act) * mask_ref[0, :]
-    partial = jax.lax.dot_general(
-        e, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (D,)
-    out_ref[...] += partial[None, :]
+    z = _hypothesis(x, w_ref[...])  # (1, TB)
+    e = glm_error(z, y_ref[...], act) * mask_ref[...]  # (1, TB)
+    out_ref[...] += jax.lax.dot_general(
+        e, x, (((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32,
+    )  # (1, D)
 
 
 def glm_grad_pallas(
@@ -66,17 +73,14 @@ def glm_grad_pallas(
         out_specs=pl.BlockSpec((1, d), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
+        name="glm_grad",
     )(x, y[None, :], w[None, :], mask[None, :])
     return out[0]
 
 
 def _glm_predict_kernel(x_ref, w_ref, mask_ref, out_ref, *, act: str):
-    x = x_ref[...]  # (TB, D) f32
-    w = w_ref[...]  # (1, D)  f32
-    z = jax.lax.dot_general(
-        x, w[0, :], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (TB,)
-    out_ref[...] = jnp.where(mask_ref[0, :] > 0.0, glm_act(z, act), 0.0)[None, :]
+    z = _hypothesis(x_ref[...], w_ref[...])  # (1, TB)
+    out_ref[...] = jnp.where(mask_ref[...] > 0.0, glm_act(z, act), 0.0)
 
 
 def glm_predict_pallas(
@@ -106,5 +110,6 @@ def glm_predict_pallas(
         out_specs=pl.BlockSpec((1, block_rows), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
+        name="glm_predict",
     )(x, w[None, :], mask[None, :])
     return out[0]

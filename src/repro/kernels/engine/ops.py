@@ -1,5 +1,5 @@
 """Jitted public wrapper for the fused GLM engine kernel: pads shapes to MXU
-tiles, dispatches kernel vs. oracle per backend, unpads."""
+tiles, dispatches kernel (TPU) vs. oracle (elsewhere) per backend, unpads."""
 from __future__ import annotations
 
 from functools import partial
@@ -43,8 +43,10 @@ def glm_grad(x, y, w, mask=None, act: str = "linear", use_kernel: bool | None = 
              block_rows: int = 128):
     """Merged GLM gradient over a tuple batch (the fused engine step).
 
-    use_kernel=None: Pallas on TPU, vectorized-jnp oracle path on CPU (same
-    math; the kernel itself is exercised in interpret mode by the test suite).
+    use_kernel=None: the Pallas kernel compiled for the TPU there
+    (``tests/test_tpu_compile.py`` compiles it for a v5e), the vectorized-jnp
+    oracle on CPU (same math; the test suite also runs the kernel in
+    interpret mode).
     """
     if mask is None:
         mask = jnp.ones(x.shape[0], dtype=jnp.float32)
@@ -118,9 +120,12 @@ def glm_grad_sharded(x, y, w, mask=None, act: str = "linear", *,
         # the fused kernel keeps z internal; the feature-dim psum must run
         # between the two matmuls, so the model-sharded path is two MXU dots
         xf = x.astype(jnp.float32)
-        z = jax.lax.psum(xf @ w.astype(jnp.float32), model_axis)
+        z = jax.lax.psum(
+            jnp.dot(xf, w.astype(jnp.float32), precision=ref.HIGHEST),
+            model_axis,
+        )
         e = ref.glm_error(z, y.astype(jnp.float32), act) * mask.astype(jnp.float32)
-        g = e @ xf
+        g = jnp.dot(e, xf, precision=ref.HIGHEST)
     if data_axes:
         g = jax.lax.psum(g, tuple(data_axes))
     return g

@@ -8,14 +8,21 @@ BlockSpec index maps, so only the pages a sequence actually owns are ever
 touched, and blocks past the token's position are skipped entirely
 (``pl.when`` on the block's first logical row vs the position).
 
-Grid: ``(T, KVH, nb_slot)`` — one token x kv-head per outer step, inner
-walk over that token's table row. Online-softmax state (running max, sum,
-value accumulator) lives in VMEM scratch, revisited across the sequential
-inner walk and flushed to the output block on the last step.
+Grid: ``(T, nb_slot)`` — one token per outer step, inner walk over that
+token's table row. Each step DMAs one whole pool block — every kv head of
+it — in the pool's own ``(NB, bs, KVH, D)`` layout, so the pool is never
+copied or transposed; the kernel then loops over the kv heads. Online-softmax
+state (running max, sum, value accumulator per kv head) lives in VMEM
+scratch, revisited across the sequential inner walk and flushed to the
+output block on the last step.
 
-``ops.py`` pads G to the 8-sublane and Dk/Dv to the 128-lane boundary
-before calling in; ``block_size`` itself is taken as-is (TPU deployments
-want it lane-aligned, the CI interpret path does not care).
+K may come in parts that split the feature dim (the absorbed-MLA decode's
+latent and rope pools): scores are the sum of the parts' dot products, so
+the parts are never concatenated. A part may be a headless ``(NB, bs, D)``
+pool (one kv head). ``v_pool=None`` reads the values from the first K part
+(MLA: the latent cache is both K and V). Blocks span whole trailing dims,
+which is what the TPU compiler's tiling rule accepts for any head count,
+head dim or block size.
 """
 from __future__ import annotations
 
@@ -27,14 +34,29 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e9
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _paged_attn_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                       m_ref, l_ref, acc_ref, *, block_size: int,
-                       ring_width: int, max_rows: int, scale: float,
-                       nb_slot: int):
+def _as_parts(x) -> tuple:
+    return tuple(x) if isinstance(x, (tuple, list)) else (x,)
+
+
+def _head(ref, h):
+    """(bs, D) rows of kv head ``h`` from a block ref: (1, bs, KVH, D), or
+    (1, bs, D) for a headless pool."""
+    return ref[0] if len(ref.shape) == 3 else ref[0, :, h, :]
+
+
+def _paged_attn_kernel(table_ref, pos_ref, *refs, n_parts: int, has_v: bool,
+                       block_size: int, ring_width: int, max_rows: int,
+                       scale: float, nb_slot: int):
+    q_refs = refs[:n_parts]
+    k_refs = refs[n_parts:2 * n_parts]
+    v_ref = refs[2 * n_parts] if has_v else k_refs[0]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * n_parts + int(has_v):]
+    kvh = q_refs[0].shape[1]
     t = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -52,13 +74,6 @@ def _paged_attn_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j * block_size <= last)
     def _block():
-        q = q_ref[0, 0].astype(jnp.float32)        # (G, Dk)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bs, Dk)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)  # (bs, Dv)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                   # (G, bs)
         rows = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_size), 1
         )
@@ -66,54 +81,77 @@ def _paged_attn_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
             valid = (rows < ring_width) & ((rows <= p) | (p >= ring_width))
         else:
             valid = (rows <= p) & (rows < max_rows)
-        s = jnp.where(valid, s, NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        probs = jnp.exp(s - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(probs, axis=-1,
+        for h in range(kvh):
+            s = sum(
+                jax.lax.dot_general(
+                    qr[0, h].astype(jnp.float32),            # (G, Dk_i)
+                    _head(kr, h).astype(jnp.float32),        # (bs, Dk_i)
+                    (((1,), (1,)), ((), ())), precision=HIGHEST,
+                    preferred_element_type=jnp.float32,
+                )
+                for qr, kr in zip(q_refs, k_refs)
+            ) * scale                                         # (G, bs)
+            s = jnp.where(valid, s, NEG)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            probs = jnp.exp(s - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(probs, axis=-1,
                                                   keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            probs, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                probs, _head(v_ref, h).astype(jnp.float32),
+                (((1,), (0,)), ((), ())), precision=HIGHEST,
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[h] = m_new
 
     @pl.when(j == nb_slot - 1)
     def _flush():
-        o_ref[0, 0] = acc_ref[...] / l_ref[...]
+        o_ref[0] = acc_ref[...] / l_ref[...]
+
+
+def _pool_spec(pool):
+    """BlockSpec of one whole pool block, addressed through the table."""
+    if pool.ndim == 3:
+        return pl.BlockSpec((1,) + pool.shape[1:],
+                            lambda ti, j, tbl, ps: (tbl[ti, j], 0, 0))
+    return pl.BlockSpec((1,) + pool.shape[1:],
+                        lambda ti, j, tbl, ps: (tbl[ti, j], 0, 0, 0))
 
 
 def paged_attn_pallas(q, k_pool, v_pool, table, pos, *, block_size: int,
                       ring_width: int = 0, max_rows: int, scale: float,
                       interpret: bool = False):
-    """q (T, KVH, G, Dk); k_pool (NB, bs, KVH, Dk); v_pool (NB, bs, KVH, Dv);
-    table (T, nb_slot) int32; pos (T,) int32. Returns (T, KVH, G, Dv) f32.
-    Shapes come in pre-padded from ops.py."""
-    t, kvh, g, dk = q.shape
-    dv = v_pool.shape[-1]
+    """q (T, KVH, G, Dk) — or a tuple of parts splitting Dk; k_pool (NB, bs,
+    KVH, Dk) — or matching parts, each possibly headless (NB, bs, Dk_i);
+    v_pool (NB, bs, KVH, Dv), or None for values = the first K part;
+    table (T, nb_slot) int32; pos (T,) int32. Returns (T, KVH, G, Dv) f32."""
+    qs, ks = _as_parts(q), _as_parts(k_pool)
+    assert len(qs) == len(ks), "one query part per K part"
+    t, kvh, g, _ = qs[0].shape
+    v = ks[0] if v_pool is None else v_pool
+    dv = v.shape[-1]
     nb_slot = table.shape[1]
-    bs = block_size
     kernel = functools.partial(
-        _paged_attn_kernel, block_size=bs, ring_width=ring_width,
-        max_rows=max_rows, scale=scale, nb_slot=nb_slot,
+        _paged_attn_kernel, n_parts=len(qs), has_v=v_pool is not None,
+        block_size=block_size, ring_width=ring_width, max_rows=max_rows,
+        scale=scale, nb_slot=nb_slot,
     )
+    pools = ks + (() if v_pool is None else (v_pool,))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(t, kvh, nb_slot),
+        grid=(t, nb_slot),
         in_specs=[
-            pl.BlockSpec((1, 1, g, dk), lambda ti, h, j, tbl, ps: (ti, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, dk),
-                         lambda ti, h, j, tbl, ps: (tbl[ti, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, dv),
-                         lambda ti, h, j, tbl, ps: (tbl[ti, j], 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dv),
-                               lambda ti, h, j, tbl, ps: (ti, h, 0, 0)),
+            pl.BlockSpec((1,) + qi.shape[1:],
+                         lambda ti, j, tbl, ps: (ti, 0, 0, 0))
+            for qi in qs
+        ] + [_pool_spec(pool) for pool in pools],
+        out_specs=pl.BlockSpec((1, kvh, g, dv),
+                               lambda ti, j, tbl, ps: (ti, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),   # running max
-            pltpu.VMEM((g, 1), jnp.float32),   # running denominator
-            pltpu.VMEM((g, dv), jnp.float32),  # value accumulator
+            pltpu.VMEM((kvh, g, 1), jnp.float32),   # running max
+            pltpu.VMEM((kvh, g, 1), jnp.float32),   # running denominator
+            pltpu.VMEM((kvh, g, dv), jnp.float32),  # value accumulator
         ],
     )
     return pl.pallas_call(
@@ -121,4 +159,5 @@ def paged_attn_pallas(q, k_pool, v_pool, table, pos, *, block_size: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, kvh, g, dv), jnp.float32),
         interpret=interpret,
-    )(table, pos, q, k_pool, v_pool)
+        name="paged_attention",
+    )(table, pos, *qs, *pools)

@@ -1,10 +1,11 @@
-"""Jitted public wrapper for the paged-attention kernel: pads query-group and
-feature dims to MXU tiles, dispatches kernel vs. oracle per backend, unpads.
+"""Jitted public wrapper for the paged-attention kernel: dispatches kernel vs.
+oracle per backend.
 
 Same contract as ``kernels/engine/ops.py``: ``use_kernel=None`` runs the
-Pallas kernel on TPU and the gather oracle on CPU (identical math; the
-kernel itself is exercised in interpret mode by the test suite, and callers
-can force it with ``use_kernel=True``)."""
+Pallas kernel on TPU — compiled natively, blocks span whole trailing dims so
+no head count, head dim or block size needs padding — and the gather oracle
+on CPU (identical math; the kernel itself is exercised in interpret mode by
+the test suite, and callers can force it with ``use_kernel=True``)."""
 from __future__ import annotations
 
 from functools import partial
@@ -15,18 +16,6 @@ import jax.numpy as jnp
 from repro.kernels.paged_attn import ref
 from repro.kernels.paged_attn.kernel import paged_attn_pallas
 
-LANES = 128
-SUBLANES = 8
-
-
-def _pad_to(x, n, axis):
-    pad = n - x.shape[axis]
-    if pad <= 0:
-        return x
-    cfg = [(0, 0)] * x.ndim
-    cfg[axis] = (0, pad)
-    return jnp.pad(x, cfg)
-
 
 def _default_use_kernel() -> bool:
     return jax.default_backend() == "tpu"
@@ -36,26 +25,12 @@ def _default_use_kernel() -> bool:
                                    "scale", "use_kernel"))
 def _paged_attn(q, k_pool, v_pool, table, pos, block_size, ring_width,
                 max_rows, scale, use_kernel):
+    kw = dict(block_size=block_size, ring_width=ring_width,
+              max_rows=max_rows, scale=scale)
     if not use_kernel:
-        return ref.paged_attn_ref(
-            q, k_pool, v_pool, table, pos, block_size=block_size,
-            ring_width=ring_width, max_rows=max_rows, scale=scale,
-        )
-    t, kvh, g, dk = q.shape
-    dv = v_pool.shape[-1]
-    gp = -(-g // SUBLANES) * SUBLANES
-    dkp = -(-dk // LANES) * LANES
-    dvp = -(-dv // LANES) * LANES
-    qp = _pad_to(_pad_to(q, gp, 2), dkp, 3)
-    kp = _pad_to(k_pool, dkp, 3)
-    vp = _pad_to(v_pool, dvp, 3)
-    interpret = jax.default_backend() == "cpu"
-    out = paged_attn_pallas(
-        qp, kp, vp, table, pos, block_size=block_size,
-        ring_width=ring_width, max_rows=max_rows, scale=scale,
-        interpret=interpret,
-    )
-    return out[:, :, :g, :dv]
+        return ref.paged_attn_ref(q, k_pool, v_pool, table, pos, **kw)
+    return paged_attn_pallas(q, k_pool, v_pool, table, pos,
+                             interpret=jax.default_backend() == "cpu", **kw)
 
 
 def paged_attention(q, k_pool, v_pool, table, pos, *, block_size: int,
@@ -63,13 +38,14 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, block_size: int,
                     use_kernel: bool | None = None):
     """Block-table paged decode attention.
 
-    q (T, KVH, G, Dk) queries (G query heads per kv head; MLA absorbed
-    decode passes KVH=1, G=n_heads, Dk=kv_lora+rope, Dv=kv_lora);
-    k_pool/v_pool (NB, bs, KVH, D*) block pools; table (T, nb_slot) int32
-    physical block ids per token (unmapped entries clamped to 0 — reads
-    through them are masked); pos (T,) int32 positions. ``ring_width`` > 0
-    selects SWA ring validity (logical rows are ``pos % ring_width``).
-    Returns (T, KVH, G, Dv) float32.
+    q (T, KVH, G, Dk) queries (G query heads per kv head); k_pool/v_pool
+    (NB, bs, KVH, D*) block pools; table (T, nb_slot) int32 physical block
+    ids per token (unmapped entries clamped to 0 — reads through them are
+    masked); pos (T,) int32 positions. ``ring_width`` > 0 selects SWA ring
+    validity (logical rows are ``pos % ring_width``). The absorbed MLA
+    decode passes KVH=1, G=n_heads, q and k_pool as (latent, rope) part
+    pairs over headless (NB, bs, D_i) pools, and ``v_pool=None`` (values are
+    the latent pool). Returns (T, KVH, G, Dv) float32.
     """
     if use_kernel is None:
         use_kernel = _default_use_kernel()

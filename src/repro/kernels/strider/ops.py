@@ -1,9 +1,10 @@
 """Jitted public wrapper for the strider kernel.
 
-Chooses the execution path per backend: the Pallas kernel (interpret=True on
-CPU — kernel-body semantics validated against ref.py and the ISA interpreter;
-compiled natively on TPU), with a VMEM working-set check the hardware
-generator performs before 'synthesis'.
+Chooses the execution path per backend: the Pallas kernel compiled natively
+on TPU (``tests/test_tpu_compile.py`` compiles it for a v5e) — or, forced on
+CPU, in interpret mode, where its semantics are validated against ref.py and
+the ISA interpreter — with a VMEM working-set check the hardware generator
+performs before 'synthesis'.
 """
 from __future__ import annotations
 
@@ -11,12 +12,13 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from repro.core.striders import ProjectionPlan
 from repro.db.page import PageLayout
 from repro.dist import meshes as dist_meshes
 from repro.kernels.strider import ref
-from repro.kernels.strider.strider import strider_decode
+from repro.kernels.strider.strider import PAGES_PER_STEP, strider_decode
 
 VMEM_BYTES = 16 * 1024 * 1024  # v5e per-core VMEM
 
@@ -32,8 +34,11 @@ DECODED_AXES = {
 
 
 def vmem_working_set(layout: PageLayout) -> int:
-    t, d = layout.tuples_per_page, layout.n_features
-    return layout.page_bytes + 4 * (t * d + 2 * t)
+    """Bytes one grid step holds in VMEM, double-buffered: a group of pages
+    plus the slot-major (T, G, D + 2) f32 output tile, lanes padded to 128."""
+    g, t = PAGES_PER_STEP, layout.tuples_per_page
+    lanes = -(-(layout.n_features + 2) // 128) * 128
+    return 2 * (g * layout.page_bytes + 4 * t * g * lanes)
 
 
 def check_vmem(layout: PageLayout) -> None:
@@ -49,6 +54,28 @@ def default_use_kernel() -> bool:
     """Kernel-selection policy, single source of truth: the Pallas kernel on
     TPU, the numerically identical (faster-to-trace) jnp path elsewhere."""
     return jax.default_backend() == "tpu"
+
+
+def _kernel_decode(pages, layout: PageLayout, plan: ProjectionPlan | None):
+    """The Pallas decode (interpret mode on CPU). Under a mesh with data
+    axes it runs per device on the device's own page range via
+    ``jax.shard_map`` — a kernel is a per-core program that GSPMD cannot
+    split. The page count is padded to the data-axes size with zero pages
+    (no live tuples), and the padding is sliced off again."""
+    decode = partial(strider_decode, layout=layout, plan=plan,
+                     interpret=jax.default_backend() == "cpu")
+    mesh = dist_meshes.current_mesh()
+    data = (dist_meshes.mesh_data_axes(mesh)
+            if isinstance(mesh, jax.sharding.Mesh) else ())
+    if not data:
+        return decode(pages)
+    p = pages.shape[0]
+    pad = -p % dist_meshes.mesh_axis_size(mesh, *data)
+    pages = jnp.pad(pages, ((0, pad), (0, 0)))
+    spec = PartitionSpec(data)
+    out = jax.shard_map(decode, mesh=mesh, in_specs=spec,
+                        out_specs=(spec, spec, spec), check_vma=False)(pages)
+    return tuple(o[:p] for o in out)
 
 
 def decode_pages_traced(
@@ -75,8 +102,7 @@ def decode_pages_traced(
     pages = jnp.asarray(pages).astype(jnp.uint32)
     pages = dist_meshes.shard_act(pages, PAGE_AXES, "strider_pages", rules=rules)
     if use_kernel:
-        interpret = jax.default_backend() == "cpu"
-        feats, labels, mask = strider_decode(pages, layout, interpret=interpret)
+        feats, labels, mask = _kernel_decode(pages, layout, None)
     else:
         feats, labels, mask = ref.decode_pages_ref(pages, layout)
     feats = dist_meshes.shard_act(
@@ -110,10 +136,7 @@ def decode_pages_projected_traced(
     pages = jnp.asarray(pages).astype(jnp.uint32)
     pages = dist_meshes.shard_act(pages, PAGE_AXES, "strider_pages", rules=rules)
     if use_kernel:
-        interpret = jax.default_backend() == "cpu"
-        feats, labels, mask = strider_decode(
-            pages, layout, interpret=interpret, plan=plan
-        )
+        feats, labels, mask = _kernel_decode(pages, layout, plan)
     else:
         feats, labels, mask = ref.decode_pages_projected_ref(pages, layout, plan)
     feats = dist_meshes.shard_act(

@@ -23,6 +23,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _dot(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sout_ref,
                 state, *, chunk: int):
     j = pl.program_id(1)
@@ -36,25 +45,26 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sout_ref,
     kk = k_ref[0].astype(jnp.float32)
     vv = v_ref[0].astype(jnp.float32)
     ll = lw_ref[0].astype(jnp.float32)
-    uu = u_ref[0].astype(jnp.float32)  # (K,)
+    uu = u_ref[0].astype(jnp.float32)  # (1, K)
 
-    cum = jnp.cumsum(ll, axis=0)  # inclusive (C, K)
-    q_ex = cum - ll  # exclusive
-    # cross-chunk contribution
-    y = jax.lax.dot(rr * jnp.exp(q_ex), s)  # (C, V)
-    # intra-chunk lower-triangular attention
-    dmat = jnp.exp(q_ex[:, None, :] - cum[None, :, :])  # (C, C, K)
-    a = jnp.einsum("tk,sk,tsk->ts", rr, kk, dmat,
-                   preferred_element_type=jnp.float32)
     t_ids = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     s_ids = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum over the chunk as a lower-triangular matmul
+    cum = _dot((s_ids <= t_ids).astype(jnp.float32), ll)  # (C, K)
+    q_ex = cum - ll  # exclusive
+    # cross-chunk contribution
+    y = _dot(rr * jnp.exp(q_ex), s)  # (C, V)
+    # intra-chunk lower-triangular attention
+    dmat = jnp.exp(q_ex[:, None, :] - cum[None, :, :])  # (C, C, K)
+    a = jnp.sum(rr[:, None, :] * kk[None, :, :] * dmat, axis=-1)  # (C, C)
     a = jnp.where(s_ids < t_ids, a, 0.0)
-    diag = jnp.sum(rr * uu[None, :] * kk, axis=-1)  # (C,)
-    y = y + jax.lax.dot(a, vv) + diag[:, None] * vv
+    diag = jnp.sum(rr * uu * kk, axis=-1)  # (C,)
+    y = y + _dot(a, vv) + diag[:, None] * vv
     # state update
-    last = cum[-1, :]  # (K,)
-    s_new = jnp.exp(last)[:, None] * s + jax.lax.dot(
-        (kk * jnp.exp(last[None, :] - cum)).T, vv
+    last = cum[chunk - 1:chunk, :]  # (1, K)
+    s_new = jnp.exp(last).T * s + jax.lax.dot_general(
+        kk * jnp.exp(last - cum), vv, (((0,), (0,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=jnp.float32,
     )
     state[...] = s_new
     y_ref[0] = y.astype(y_ref.dtype)
@@ -84,7 +94,7 @@ def wkv_pallas(r, k, v, lw, u, state, chunk: int, interpret: bool = False):
             pl.BlockSpec((1, chunk, kd), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, chunk, vd), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, chunk, kd), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, kd), lambda i, j: (i % h, 0)),
+            pl.BlockSpec((1, 1, kd), lambda i, j: (i % h, 0, 0)),
             pl.BlockSpec((1, kd, vd), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
@@ -97,7 +107,8 @@ def wkv_pallas(r, k, v, lw, u, state, chunk: int, interpret: bool = False):
         ],
         scratch_shapes=[pltpu.VMEM((kd, vd), jnp.float32)],
         interpret=interpret,
-    )(rb, kb, vb, lb, u, s0)
+        name="wkv",
+    )(rb, kb, vb, lb, u[:, None, :], s0)
 
     y = y.reshape(b, h, t, vd).transpose(0, 2, 1, 3)
     return y, s_out.reshape(b, h, kd, vd)

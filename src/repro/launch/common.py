@@ -12,6 +12,9 @@ same concept is spelled the same way — same name, same default — everywhere:
                    where a chaos plan makes sense)
   bench output     ``--bench-out PATH`` writing a JSON rollup
 
+``enable_compile_cache()`` turns on JAX's persistent compilation cache; each
+launcher calls it at the top of ``main`` (never at import).
+
 Every helper takes the ``argparse.ArgumentParser`` (or a group) and only
 *adds* arguments — launchers keep their workload-specific flags alongside.
 """
@@ -19,6 +22,28 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
+
+# the checkout root (src/repro/launch/common.py -> four levels up)
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache (JAX reads it) and
+    nothing else is set. Otherwise the cache is ``.jax_cache/`` in the
+    checkout: a fixed path, since a cache whose directory moves never hits.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def add_mesh_flags(ap: argparse.ArgumentParser, *, default_mesh: str = "none") -> None:

@@ -40,6 +40,7 @@ from repro.dist import meshes  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.models import model_zoo  # noqa: E402
 from repro.roofline.hlo import collective_stats  # noqa: E402
+from repro.launch import common  # noqa: E402
 from repro.train import optimizer as opt_mod  # noqa: E402
 
 ARTIFACT_DIR = os.path.join("artifacts", "dryrun")
@@ -330,6 +331,7 @@ def main():
     ap.add_argument("--out", default=ARTIFACT_DIR)
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    common.enable_compile_cache()
 
     cells = []
     archs = list(ARCH_IDS) if args.all or not args.arch else [args.arch]

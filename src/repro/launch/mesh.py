@@ -3,8 +3,7 @@
 mesh-shaped. Import from there in new code."""
 from repro.dist.meshes import (  # noqa: F401
     make_host_mesh,
-    make_mesh,
     make_production_mesh,
 )
 
-__all__ = ["make_host_mesh", "make_mesh", "make_production_mesh"]
+__all__ = ["make_host_mesh", "make_production_mesh"]

@@ -25,6 +25,7 @@ import argparse  # noqa: E402
 import json  # noqa: E402
 
 from repro.launch.dryrun import lower_cell  # noqa: E402
+from repro.launch import common  # noqa: E402
 
 PERF_DIR = os.path.join("artifacts", "perf")
 
@@ -121,6 +122,7 @@ def main():
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    common.enable_compile_cache()
 
     labels = (
         [args.label]

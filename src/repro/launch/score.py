@@ -66,6 +66,7 @@ def main(argv=None):
     common.add_scheduler_flags(ap, faults=False)
     common.add_bench_out_flag(ap)
     args = ap.parse_args(argv)
+    common.enable_compile_cache()
 
     root = args.workdir or tempfile.mkdtemp(prefix="dana_score_")
     rng = np.random.default_rng(args.seed)

@@ -85,6 +85,7 @@ def main(argv=None):
     common.add_scheduler_flags(ap, faults=True)
     common.add_bench_out_flag(ap)
     args = ap.parse_args(argv)
+    common.enable_compile_cache()
 
     cfg = get_reduced_config(args.arch) if args.reduced else get_config(args.arch)
     if cfg.family == "encdec":

@@ -47,6 +47,7 @@ def main(argv=None):
     common.add_bench_out_flag(ap)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    common.enable_compile_cache()
 
     cfg = get_reduced_config(args.arch) if args.reduced else get_config(args.arch)
     print(f"[train] {cfg.name} ({'reduced' if args.reduced else 'full'}): "

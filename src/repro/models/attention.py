@@ -430,7 +430,8 @@ def mla_decode_paged(p, x, cache, pos, cfg: ModelConfig, table, block_size,
     The latent cache has no head dim, so paging is the only sharding lever
     it gets (blocks over the data axes). ``impl="pallas"`` runs the absorbed
     attention as one MQA call on the block-walking kernel: K is the latent
-    concat [c ; kr] shared by every head, V is the latent c."""
+    pool and the rope pool as two parts shared by every head (never
+    concatenated), V is the latent pool."""
     dt = x.dtype
     b = x.shape[0]
     pos_b = _batch_pos(pos, b)
@@ -451,10 +452,10 @@ def mla_decode_paged(p, x, cache, pos, cfg: ModelConfig, table, block_size,
     max_rows = (max_seq if max_seq is not None
                 else table.shape[1] * block_size)
     if impl == "pallas":
-        q_eff = jnp.concatenate([q_lat, qr], axis=-1)[:, 0][:, None]
-        k_eff = jnp.concatenate([c, kr], axis=-1)[:, :, None, :]
+        # (b, 1, H, *) queries read as (T=b, KVH=1, G=H, *); the latent and
+        # rope pools are the two K parts, the latent pool is also V
         out_lat = paged_attn_ops.paged_attention(
-            q_eff, k_eff, c[:, :, None, :], table, pos_b,
+            (q_lat, qr), (c, kr), None, table, pos_b,
             block_size=block_size, ring_width=0, max_rows=max_rows,
             scale=scale,
         )[:, 0].astype(dt)

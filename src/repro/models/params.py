@@ -62,7 +62,10 @@ def stack_layers(maker_fn, n_layers: int):
     def stack(trees):
         first = trees[0]
         if isinstance(first, dict):
-            return {k: stack([t[k] for t in trees]) for k in first}
+            # pop, so each leaf's per-layer arrays are freed once stacked:
+            # device memory peaks at the per-layer arrays plus one stacked
+            # leaf, not at twice the model
+            return {k: stack([t.pop(k) for t in trees]) for k in list(first)}
         arrs = [t[0] for t in trees]
         axes = ("layers",) + first[1]
         if isinstance(arrs[0], jax.ShapeDtypeStruct):

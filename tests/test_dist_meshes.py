@@ -280,7 +280,9 @@ _MULTI_DEVICE_SCRIPT = textwrap.dedent(
 def test_engine_sharded_epoch_parity_8_devices_subprocess():
     """True data-parallel run: 8 forced host devices, threads sharded over
     the data axis, results equal to the single-device engine."""
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # forced host devices are CPU devices: the child never takes the chip
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-c", _MULTI_DEVICE_SCRIPT],

@@ -13,7 +13,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_dryrun_cell_subprocess(tmp_path):
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # forced host devices are CPU devices: the child never takes the chip
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)  # dryrun.py must set it itself (first lines)
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",

@@ -113,9 +113,40 @@ def test_kernel_shared_blocks_across_tokens():
     _run_both(q, k, v, table, pos, bs=4, ring_width=0, max_rows=16)
 
 
+@pytest.mark.parametrize("ring_width", [0, 6])
+def test_kernel_k_parts_headless_pools(ring_width):
+    """The absorbed-MLA argument form: K split into a latent and a rope part
+    over headless (NB, bs, D_i) pools, values read from the first K part —
+    the kernel matches the oracle given the same parts, and the oracle
+    matches plain attention over the concatenated K."""
+    t, g, bs, nb_slot, num_blocks = 6, 5, 2, 4, 32
+    rng = np.random.default_rng(8)
+    q_lat = rng.normal(0, 1, (t, 1, g, 16)).astype(np.float32)
+    q_rope = rng.normal(0, 1, (t, 1, g, 4)).astype(np.float32)
+    c = rng.normal(0, 1, (num_blocks, bs, 16)).astype(np.float32)
+    kr = rng.normal(0, 1, (num_blocks, bs, 4)).astype(np.float32)
+    table = rng.permutation(num_blocks)[: t * nb_slot].reshape(t, nb_slot)
+    pos = np.array([0, 1, 3, 4, 7, 12], np.int32)
+    kw = dict(block_size=bs, ring_width=ring_width, max_rows=nb_slot * bs,
+              scale=0.3)
+    args = ((q_lat, q_rope), (c, kr), None, jnp.asarray(table, jnp.int32),
+            jnp.asarray(pos))
+    got = paged_attn_pallas(*args, interpret=True, **kw)
+    want = ref.paged_attn_ref(*args, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    concat = ref.paged_attn_ref(
+        np.concatenate([q_lat, q_rope], -1),
+        np.concatenate([c, kr], -1)[:, :, None, :], c[:, :, None, :],
+        jnp.asarray(table, jnp.int32), jnp.asarray(pos), **kw,
+    )
+    np.testing.assert_allclose(np.asarray(want), np.asarray(concat),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_ops_padding_and_dispatch():
-    """The jitted wrapper pads G to sublanes and Dk/Dv to lanes before the
-    kernel and unpads after; forced kernel and oracle dispatch agree."""
+    """Odd G/Dk/Dv go through the jitted wrapper unpadded (blocks span whole
+    trailing dims); forced kernel and oracle dispatch agree."""
     q, k, v, table, pos, max_rows = _case(t=3, kvh=2, g=3, dk=5, dv=7, seed=7)
     kw = dict(block_size=4, ring_width=0, max_rows=max_rows, scale=0.21)
     got = ops.paged_attention(q, k, v, table, pos, use_kernel=True, **kw)

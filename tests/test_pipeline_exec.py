@@ -63,6 +63,44 @@ def test_run_chunk_matches_decode_then_epoch(linreg_heap):
     assert len(eng._chunk_fns) == 1
 
 
+def test_lower_chunk_is_the_dispatched_program(linreg_heap):
+    """``Engine.lower_chunk`` lowers the very program ``run_chunk`` caches
+    and runs (what a chip run inspects for its kernels)."""
+    heap, _ = linreg_heap
+    g, part = trace(lambda: linear_regression(16, lr=0.3, merge_coef=64))
+    eng = make_engine(g, part)
+    models = init_models(g, np.random.default_rng(0), scale=0.01)
+    pages = heap.read_pages(np.arange(4))
+    compiled = eng.lower_chunk(models, pages, heap.layout).compile()
+    got, _ = compiled(models, jnp.asarray(pages))
+    want, _ = eng.run_chunk(models, pages, heap.layout)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    assert len(eng._chunk_fns) == 1
+
+
+@pytest.mark.parametrize("pipelined", [False, True])
+def test_train_use_kernel_paths_agree(linreg_heap, monkeypatch, pipelined):
+    """``use_kernel`` reaches the decode and the engine: True runs the
+    strider kernel (interpret mode here), False the reference decode and the
+    vmapped update rule — the plain float32 path — with equal results."""
+    heap, _ = linreg_heap
+    monkeypatch.setattr(solver, "MAX_RESIDENT_PAGES", 8)
+    g, part = trace(lambda: linear_regression(16, lr=0.3, merge_coef=64,
+                                              epochs=2))
+    seen = []
+    real = solver.make_engine
+    monkeypatch.setattr(
+        solver, "make_engine",
+        lambda *a, **kw: seen.append(kw["use_fused_kernel"]) or real(*a, **kw),
+    )
+    a = solver.train(g, part, heap, seed=3, pipelined=pipelined,
+                     use_kernel=True)
+    b = solver.train(g, part, heap, seed=3, pipelined=pipelined,
+                     use_kernel=False)
+    assert seen == [True, False]
+    np.testing.assert_allclose(a.models[0], b.models[0], rtol=1e-5, atol=1e-6)
+
+
 # ------------------------- pipelined solver.train ----------------------------
 @pytest.mark.parametrize("mode", ["dana", "dana-nostrider"])
 def test_pipelined_matches_synchronous_train(linreg_heap, monkeypatch, mode):
@@ -262,7 +300,9 @@ def test_pipelined_train_sharded_8_devices_subprocess():
     """The fused chunk program under a real 8-device data axis: decode,
     sharding constraints, and the cross-device merge run inside one jitted
     program per chunk, numerically equal to the single-device pipeline."""
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # forced host devices are CPU devices: the child never takes the chip
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-c", _SHARDED_SCRIPT],

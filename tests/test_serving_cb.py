@@ -513,7 +513,9 @@ _MULTI_DEVICE_SCRIPT = textwrap.dedent(
 def test_sharded_serving_8_devices_subprocess():
     """8 forced host devices: mesh-sharded KV cache (slots over data, heads
     over model) matches single-device decode; admission exact under mesh."""
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # forced host devices are CPU devices: the child never takes the chip
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-c", _MULTI_DEVICE_SCRIPT],
@@ -585,7 +587,9 @@ def test_sharded_paged_pool_8_devices_subprocess():
     — blocks over data, kv heads over model — token-exact vs single-device
     paged AND dense serving, with the divisibility fallback recorded when
     the block count does not divide the data axes."""
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # forced host devices are CPU devices: the child never takes the chip
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-c", _PAGED_MULTI_DEVICE_SCRIPT],

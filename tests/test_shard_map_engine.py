@@ -293,7 +293,9 @@ _MULTI_DEVICE_SCRIPT = textwrap.dedent(
 def test_shard_map_engine_8_devices_subprocess():
     """8 forced host devices: fused-kernel sharded epoch (vmap fallback
     poisoned), model-axis GLM + LRMF parity, solver.train(shard_model=True)."""
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # forced host devices are CPU devices: the child never takes the chip
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-c", _MULTI_DEVICE_SCRIPT],
@@ -355,7 +357,9 @@ def test_shard_map_float64_parity_8_devices_subprocess():
     """shard_map vs single-core at float64: the cross-device psum merge is
     numerically the same sum, so parity tightens to ~1e-12 — float32 gaps in
     the f32 suite are reduction order, not a datapath bug."""
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # forced host devices are CPU devices: the child never takes the chip
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-c", _FLOAT64_SCRIPT],

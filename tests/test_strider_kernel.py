@@ -1,5 +1,10 @@
 """Pallas strider kernel: interpret-mode validation against the jnp oracle,
 the ISA interpreter, and the honest parser — swept over shapes/dtypes."""
+import os
+import subprocess
+import sys
+import textwrap
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -92,3 +97,52 @@ def test_kernel_property(n, d, quant, seed):
         k = wf.shape[0]
         np.testing.assert_array_equal(np.asarray(kf[i])[:k], wf)
         np.testing.assert_array_equal(np.asarray(kl[i])[:k], wl)
+
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_MESH_SCRIPT = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.core.striders import projection_plan
+    from repro.db.page import PageLayout, build_pages
+    from repro.dist import meshes
+    from repro.kernels.strider import ops, ref
+
+    lo = PageLayout(n_features=13, page_bytes=4096, quantized=False)
+    rng = np.random.default_rng(0)
+    n = 9 * lo.tuples_per_page - 5  # 9 pages: not a multiple of 4 devices
+    pages = jnp.asarray(build_pages(rng.normal(0, 1, (n, 13)).astype(np.float32),
+                                    rng.normal(0, 1, n).astype(np.float32), lo))
+    plan = projection_plan(lo, [0, 4, 5, 12], include_label=True)
+    for mp in (1, 2):
+        mesh = meshes.make_host_mesh(model_parallel=mp)
+        with meshes.use_mesh(mesh):
+            full = jax.jit(lambda p: ops.decode_pages_traced(p, lo, True))(pages)
+            proj = jax.jit(lambda p: ops.decode_pages_projected_traced(
+                p, lo, plan, True))(pages)
+        for got, want in zip(full, ref.decode_pages_ref(pages, lo)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        for got, want in zip(proj, ref.decode_pages_projected_ref(pages, lo, plan)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    print("MESH-DECODE-OK")
+    """
+)
+
+
+def test_kernel_decode_under_mesh_subprocess():
+    """Under a mesh with data axes the kernel runs per device on its own
+    page range (shard_map), with the page count padded to the device count
+    and the padding sliced off: bit-equal to the reference decode, full and
+    projected, on data and data x model meshes."""
+    # forced host devices are CPU devices: the child never takes the chip
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, "-c", _MESH_SCRIPT],
+        env=env, capture_output=True, text=True, timeout=600, cwd=REPO,
+    )
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    assert "MESH-DECODE-OK" in out.stdout
