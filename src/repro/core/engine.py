@@ -476,12 +476,12 @@ class Engine:
 
         sharded_path = None
         epoch = None
+        rules = self._batch_rules()
         if mesh is not None:
             sharded_path = self.sharded_path(mesh)
             path, data_axes, model_axis = sharded_path
             if path == "shard_map":
                 epoch = self._shard_map_epoch(mesh, data_axes, model_axis)
-            rules = self._batch_rules()
 
         def impl(models, pages):
             if mesh is not None:
@@ -493,9 +493,10 @@ class Engine:
                     rules=rules, tensor_name="engine_pages",
                 )
                 pages = jax.lax.with_sharding_constraint(pages, sh)
+            # the decode runs on this program's mesh (shard_map'd per
+            # device), not on whatever ``use_mesh`` is installed at trace time
             feats, labels, mask = strider_ops.decode_pages_traced(
-                pages, layout, use_kernel,
-                rules=rules if mesh is not None else None,
+                pages, layout, use_kernel, rules=rules, mesh=mesh,
             )
             t = feats.shape[0] * feats.shape[1]
             X, Y, M = batches_from_stream(
@@ -540,15 +541,14 @@ class Engine:
             models = self._place_models(models, mesh, sharded_path[2])
         return fn(models, jnp.asarray(pages))
 
-    def lower_chunk(self, models, pages, layout, use_kernel: bool | None = None):
+    def lower_chunk(self, models, pages, layout):
         """The fused chunk program ``run_chunk`` dispatches for these
-        arguments, lowered: ``.compile().as_text()`` shows the kernels in it
-        (``tpu_custom_call``)."""
+        arguments by default, lowered: ``.compile().as_text()`` shows the
+        kernels in it (``tpu_custom_call``)."""
         from repro.kernels.strider import ops as strider_ops
 
-        if use_kernel is None:
-            use_kernel = strider_ops.default_use_kernel()
-        fn, _ = self._chunk_fn(layout, bool(use_kernel), self._active_mesh())
+        fn, _ = self._chunk_fn(layout, strider_ops.default_use_kernel(),
+                               self._active_mesh())
         return fn.lower(models, jnp.asarray(pages))
 
     def converged(self, models, merged) -> bool:

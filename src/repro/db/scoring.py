@@ -161,8 +161,7 @@ def _scoring_model(artifact: dict, udf: str) -> np.ndarray:
 
 
 def _build_glm_chunk_fn(layout, plan, family, model, where, where_pos,
-                        use_kernel, aggregates=None, agg_pos=None,
-                        proj_pos=()):
+                        use_kernel, proj_pos, aggregates=None, agg_pos=None):
     """One fused device program per chunk: projected strider decode + WHERE
     keep-mask (the whole predicate tree evaluates traced) + model scoring.
 
@@ -177,6 +176,7 @@ def _build_glm_chunk_fn(layout, plan, family, model, where, where_pos,
     import jax
     import jax.numpy as jnp
 
+    from repro.dist.meshes import current_mesh
     from repro.kernels.engine import ops as engine_ops
     from repro.kernels.strider import ops as strider_ops
 
@@ -188,8 +188,9 @@ def _build_glm_chunk_fn(layout, plan, family, model, where, where_pos,
 
     @jax.jit
     def run(pages):
+        # scoring has no mesh of its own: it decodes on the use_mesh one
         feats, labels, mask = strider_ops.decode_pages_projected_traced(
-            pages, layout, plan, use_kernel
+            pages, layout, plan, use_kernel, mesh=current_mesh()
         )
         p, t, c = feats.shape
         f2 = feats.reshape(p * t, c)
@@ -228,9 +229,8 @@ def _build_glm_chunk_fn(layout, plan, family, model, where, where_pos,
                     jnp.sum(jnp.where(keep, val.astype(jnp.float32), 0.0))
                 )
             return jnp.stack(sums), jnp.sum(keep.astype(jnp.int32))
-        cols = [lab if pos is None else f2[:, pos] for pos in proj_pos]
-        cols = (jnp.stack(cols, axis=1) if cols
-                else jnp.zeros((p * t, 0), jnp.float32))
+        cols = jnp.stack([lab if pos is None else f2[:, pos]
+                          for pos in proj_pos], axis=1)
         return preds, keep, cols
 
     return run
@@ -389,9 +389,9 @@ class PredictScan:
         }
         self.run_chunk = _build_glm_chunk_fn(
             layout, plan, family, model, stmt.where, where_pos, use_kernel,
+            [None if idx is None else plan.columns.index(idx)
+             for idx in proj_idx],
             aggregates=stmt.aggregates, agg_pos=agg_pos,
-            proj_pos=[None if idx is None else plan.columns.index(idx)
-                      for idx in proj_idx],
         )
         self.page_chunks = [
             np.arange(s, min(s + self.chunk, self.heap.n_pages))
@@ -562,6 +562,7 @@ def _predict_lm(stmt, catalog, artifact, heap, pool, chunk, t_start, *,
     import jax
 
     from repro.db import query as q
+    from repro.dist.meshes import current_mesh
     from repro.kernels.strider import ops as strider_ops
     from repro.serve.serving import score_tokens
 
@@ -575,7 +576,7 @@ def _predict_lm(stmt, catalog, artifact, heap, pool, chunk, t_start, *,
     @jax.jit
     def run(pages):
         return strider_ops.decode_pages_projected_traced(
-            pages, layout, plan, use_kernel
+            pages, layout, plan, use_kernel, mesh=current_mesh()
         )
 
     outs, exposed, overlapped, compute = _scan_chunks(heap, pool, chunk, run)

@@ -309,7 +309,13 @@ def shard_act(x, logical_axes, tag: str | None = None, rules: dict | None = None
     mesh; identity when no mesh is installed (single-process tests) or when
     the spec resolves fully replicated. Resolution happens at trace time —
     enter ``use_mesh`` before jitting."""
-    mesh = current_mesh()
+    return constrain(x, logical_axes, current_mesh(), tag=tag, rules=rules)
+
+
+def constrain(x, logical_axes, mesh, tag: str | None = None,
+              rules: dict | None = None):
+    """``shard_act`` on an explicit ``mesh`` (None: identity) — for callers
+    that resolve their mesh themselves rather than from ``use_mesh``."""
     if mesh is None:
         return x
     spec = resolve_spec(

@@ -47,6 +47,19 @@ def _head(ref, h):
     return ref[0] if len(ref.shape) == 3 else ref[0, :, h, :]
 
 
+def _scores(q, k):
+    """(G, D) x (bs, D) -> (G, bs) f32 scores. Products of two bf16 values
+    are exact in f32, so bf16 operands take one MXU pass with f32
+    accumulation; wider operands run at full f32 precision."""
+    dims = (((1,), (1,)), ((), ()))
+    if q.dtype == k.dtype == jnp.bfloat16:
+        return jax.lax.dot_general(q, k, dims,
+                                   preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(q.astype(jnp.float32), k.astype(jnp.float32),
+                               dims, precision=HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
 def _paged_attn_kernel(table_ref, pos_ref, *refs, n_parts: int, has_v: bool,
                        block_size: int, ring_width: int, max_rows: int,
                        scale: float, nb_slot: int):
@@ -83,12 +96,7 @@ def _paged_attn_kernel(table_ref, pos_ref, *refs, n_parts: int, has_v: bool,
             valid = (rows <= p) & (rows < max_rows)
         for h in range(kvh):
             s = sum(
-                jax.lax.dot_general(
-                    qr[0, h].astype(jnp.float32),            # (G, Dk_i)
-                    _head(kr, h).astype(jnp.float32),        # (bs, Dk_i)
-                    (((1,), (1,)), ((), ())), precision=HIGHEST,
-                    preferred_element_type=jnp.float32,
-                )
+                _scores(qr[0, h], _head(kr, h))  # (G, Dk_i) x (bs, Dk_i)
                 for qr, kr in zip(q_refs, k_refs)
             ) * scale                                         # (G, bs)
             s = jnp.where(valid, s, NEG)
@@ -98,6 +106,7 @@ def _paged_attn_kernel(table_ref, pos_ref, *refs, n_parts: int, has_v: bool,
             probs = jnp.exp(s - m_new)
             l_ref[h] = l_ref[h] * alpha + jnp.sum(probs, axis=-1,
                                                   keepdims=True)
+            # probs are f32: this dot needs full f32 precision
             acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
                 probs, _head(v_ref, h).astype(jnp.float32),
                 (((1,), (0,)), ((), ())), precision=HIGHEST,

@@ -56,15 +56,15 @@ def default_use_kernel() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _kernel_decode(pages, layout: PageLayout, plan: ProjectionPlan | None):
-    """The Pallas decode (interpret mode on CPU). Under a mesh with data
+def _kernel_decode(pages, layout: PageLayout, plan: ProjectionPlan | None,
+                   mesh):
+    """The Pallas decode (interpret mode on CPU). On a ``mesh`` with data
     axes it runs per device on the device's own page range via
     ``jax.shard_map`` — a kernel is a per-core program that GSPMD cannot
     split. The page count is padded to the data-axes size with zero pages
     (no live tuples), and the padding is sliced off again."""
     decode = partial(strider_decode, layout=layout, plan=plan,
                      interpret=jax.default_backend() == "cpu")
-    mesh = dist_meshes.current_mesh()
     data = (dist_meshes.mesh_data_axes(mesh)
             if isinstance(mesh, jax.sharding.Mesh) else ())
     if not data:
@@ -78,9 +78,30 @@ def _kernel_decode(pages, layout: PageLayout, plan: ProjectionPlan | None):
     return tuple(o[:p] for o in out)
 
 
+def _decode(pages, layout, plan, use_kernel, rules, mesh):
+    """Shared body of the traced decodes: pin the page stream and the
+    decoded tensors over ``mesh`` (identity when None) around the kernel or
+    the reference decode."""
+    check_vmem(layout)
+    if use_kernel is None:
+        use_kernel = default_use_kernel()
+    pages = jnp.asarray(pages).astype(jnp.uint32)
+    pages = dist_meshes.constrain(pages, PAGE_AXES, mesh, "strider_pages", rules)
+    if use_kernel:
+        out = _kernel_decode(pages, layout, plan, mesh)
+    elif plan is None:
+        out = ref.decode_pages_ref(pages, layout)
+    else:
+        out = ref.decode_pages_projected_ref(pages, layout, plan)
+    return tuple(
+        dist_meshes.constrain(x, DECODED_AXES[k], mesh, f"strider_{k}", rules)
+        for k, x in zip(("feats", "labels", "mask"), out)
+    )
+
+
 def decode_pages_traced(
     pages, layout: PageLayout, use_kernel: bool | None = None,
-    rules: dict | None = None,
+    rules: dict | None = None, mesh=None,
 ):
     """Trace-time decode body: safe to call inside an enclosing ``jax.jit``.
 
@@ -89,78 +110,44 @@ def decode_pages_traced(
     through a separate dispatch. ``check_vmem`` runs at trace time (layout is
     static), exactly as the hardware generator checks before synthesis.
 
-    Under an active ``meshes.use_mesh`` the page stream and its decoded
-    tensors are constrained over the mesh's data axes (``PAGE_AXES`` /
-    ``DECODED_AXES``), so GSPMD partitions the decode page-parallel — each
-    device's Strider walks its own page range. ``rules`` selects the rule
-    table (the engine passes ``MODEL_SHARD_RULES`` when the feature dim is
-    model-sharded); identity outside a mesh context.
+    On a ``mesh`` the page stream and its decoded tensors are constrained
+    over the mesh's data axes (``PAGE_AXES`` / ``DECODED_AXES``) and the
+    kernel runs per device — each device's Strider walks its own page range.
+    ``rules`` selects the rule table (the engine passes ``MODEL_SHARD_RULES``
+    when the feature dim is model-sharded). ``mesh=None`` decodes on one
+    device; the caller resolves the mesh, so the decode and the program
+    around it cannot disagree about it.
     """
-    check_vmem(layout)
-    if use_kernel is None:
-        use_kernel = default_use_kernel()
-    pages = jnp.asarray(pages).astype(jnp.uint32)
-    pages = dist_meshes.shard_act(pages, PAGE_AXES, "strider_pages", rules=rules)
-    if use_kernel:
-        feats, labels, mask = _kernel_decode(pages, layout, None)
-    else:
-        feats, labels, mask = ref.decode_pages_ref(pages, layout)
-    feats = dist_meshes.shard_act(
-        feats, DECODED_AXES["feats"], "strider_feats", rules=rules
-    )
-    labels = dist_meshes.shard_act(
-        labels, DECODED_AXES["labels"], "strider_labels", rules=rules
-    )
-    mask = dist_meshes.shard_act(
-        mask, DECODED_AXES["mask"], "strider_mask", rules=rules
-    )
-    return feats, labels, mask
+    return _decode(pages, layout, None, use_kernel, rules, mesh)
 
 
 def decode_pages_projected_traced(
     pages, layout: PageLayout, plan: ProjectionPlan,
-    use_kernel: bool | None = None, rules: dict | None = None,
+    use_kernel: bool | None = None, rules: dict | None = None, mesh=None,
 ):
     """Trace-time pushdown decode body (safe inside an enclosing ``jax.jit``).
 
-    Same fusion contract as :func:`decode_pages_traced`, but the decode is
-    restricted to ``plan``'s payload words — the scoring executor composes
-    this with filter evaluation and model scoring into one device program, so
-    dropped columns never leave the page buffer and filtered tuples never
-    reach the engine. ``plan`` is static (frozen dataclass of tuples): it is
-    part of the jit cache key, exactly like the layout.
+    Same fusion and mesh contract as :func:`decode_pages_traced`, but the
+    decode is restricted to ``plan``'s payload words — the scoring executor
+    composes this with filter evaluation and model scoring into one device
+    program, so dropped columns never leave the page buffer and filtered
+    tuples never reach the engine. ``plan`` is static (frozen dataclass of
+    tuples): it is part of the jit cache key, exactly like the layout.
     """
-    check_vmem(layout)
-    if use_kernel is None:
-        use_kernel = default_use_kernel()
-    pages = jnp.asarray(pages).astype(jnp.uint32)
-    pages = dist_meshes.shard_act(pages, PAGE_AXES, "strider_pages", rules=rules)
-    if use_kernel:
-        feats, labels, mask = _kernel_decode(pages, layout, plan)
-    else:
-        feats, labels, mask = ref.decode_pages_projected_ref(pages, layout, plan)
-    feats = dist_meshes.shard_act(
-        feats, DECODED_AXES["feats"], "strider_feats", rules=rules
-    )
-    labels = dist_meshes.shard_act(
-        labels, DECODED_AXES["labels"], "strider_labels", rules=rules
-    )
-    mask = dist_meshes.shard_act(
-        mask, DECODED_AXES["mask"], "strider_mask", rules=rules
-    )
-    return feats, labels, mask
-
-
-@partial(jax.jit, static_argnums=(1, 2))
-def _decode_jit(pages, layout: PageLayout, use_kernel: bool):
-    return decode_pages_traced(pages, layout, use_kernel)
+    return _decode(pages, layout, plan, use_kernel, rules, mesh)
 
 
 @partial(jax.jit, static_argnums=(1, 2, 3))
+def _decode_jit(pages, layout: PageLayout, use_kernel: bool, mesh):
+    return decode_pages_traced(pages, layout, use_kernel, mesh=mesh)
+
+
+@partial(jax.jit, static_argnums=(1, 2, 3, 4))
 def _decode_projected_jit(
-    pages, layout: PageLayout, plan: ProjectionPlan, use_kernel: bool
+    pages, layout: PageLayout, plan: ProjectionPlan, use_kernel: bool, mesh
 ):
-    return decode_pages_projected_traced(pages, layout, plan, use_kernel)
+    return decode_pages_projected_traced(pages, layout, plan, use_kernel,
+                                         mesh=mesh)
 
 
 def decode_pages_projected(
@@ -171,12 +158,14 @@ def decode_pages_projected(
     if use_kernel is None:
         use_kernel = default_use_kernel()
     return _decode_projected_jit(
-        jnp.asarray(pages, dtype=jnp.uint32), layout, plan, bool(use_kernel)
+        jnp.asarray(pages, dtype=jnp.uint32), layout, plan, bool(use_kernel),
+        dist_meshes.current_mesh(),
     )
 
 
 def decode_pages(pages: jnp.ndarray, layout: PageLayout, use_kernel: bool | None = None):
-    """Decode a batch of pages on-device (standalone jitted dispatch).
+    """Decode a batch of pages on-device (standalone jitted dispatch), on
+    the ``use_mesh`` mesh when one is installed.
 
     use_kernel=None picks the Pallas kernel on TPU and the (numerically
     identical, faster-to-trace) vectorized jnp path on CPU — both are the
@@ -184,4 +173,5 @@ def decode_pages(pages: jnp.ndarray, layout: PageLayout, use_kernel: bool | None
     """
     if use_kernel is None:
         use_kernel = default_use_kernel()  # concrete for the jit cache key
-    return _decode_jit(jnp.asarray(pages, dtype=jnp.uint32), layout, bool(use_kernel))
+    return _decode_jit(jnp.asarray(pages, dtype=jnp.uint32), layout,
+                       bool(use_kernel), dist_meshes.current_mesh())

@@ -310,3 +310,61 @@ def test_pipelined_train_sharded_8_devices_subprocess():
     )
     assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
     assert "SHARDED-RUN-CHUNK-OK" in out.stdout
+
+
+_ENGINE_MESH_SCRIPT = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import numpy as np
+    import jax
+
+    from repro.algorithms import linear_regression
+    from repro.core.engine import init_models, make_engine
+    from repro.core.translator import trace
+    from repro.db.page import PageLayout, build_pages
+    from repro.dist import meshes
+    from repro.kernels.strider import ops
+
+    lo = PageLayout(n_features=12, page_bytes=4096, quantized=False)
+    rng = np.random.default_rng(3)
+    n = 10 * lo.tuples_per_page - 3  # 10 pages: padded to 12 over 4 devices
+    pages = build_pages(rng.normal(0, 1, (n, 12)).astype(np.float32),
+                        rng.normal(0, 1, n).astype(np.float32), lo)
+    g, part = trace(lambda: linear_regression(12, lr=0.3, merge_coef=64))
+    models = init_models(g, np.random.default_rng(0), scale=0.01)
+
+    seen = []
+    real = ops.strider_decode
+    ops.strider_decode = lambda p, **kw: seen.append(p.shape[0]) or real(p, **kw)
+
+    want, _ = make_engine(g, part).run_chunk(models, pages, lo, use_kernel=True)
+    assert seen == [10], seen
+    mesh = meshes.make_host_mesh()
+    assert meshes.current_mesh() is None
+    eng = make_engine(g, part, mesh=mesh)  # no use_mesh around the run
+    got, _ = eng.run_chunk(models, pages, lo, use_kernel=True)
+    assert eng.last_sharded_path[0] == "shard_map", eng.last_sharded_path
+    # the kernel ran per device on its own 3-page range, not on all 10 pages
+    assert seen == [10, 3], seen
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=1e-5, atol=1e-6)
+    print("ENGINE-MESH-DECODE-OK")
+    """
+)
+
+
+def test_engine_mesh_shards_the_kernel_decode_subprocess():
+    """An engine built with ``mesh=`` and run outside ``use_mesh`` decodes
+    on its own mesh: the strider kernel is shard_map'd per device over the
+    page range, and the result equals the one-device chunk program."""
+    # forced host devices are CPU devices: the child never takes the chip
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, "-c", _ENGINE_MESH_SCRIPT],
+        env=env, capture_output=True, text=True, timeout=600, cwd=REPO,
+    )
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    assert "ENGINE-MESH-DECODE-OK" in out.stdout

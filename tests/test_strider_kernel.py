@@ -118,10 +118,14 @@ _MESH_SCRIPT = textwrap.dedent(
     plan = projection_plan(lo, [0, 4, 5, 12], include_label=True)
     for mp in (1, 2):
         mesh = meshes.make_host_mesh(model_parallel=mp)
-        with meshes.use_mesh(mesh):
-            full = jax.jit(lambda p: ops.decode_pages_traced(p, lo, True))(pages)
-            proj = jax.jit(lambda p: ops.decode_pages_projected_traced(
-                p, lo, plan, True))(pages)
+        full = jax.jit(lambda p: ops.decode_pages_traced(
+            p, lo, True, mesh=mesh))(pages)
+        proj = jax.jit(lambda p: ops.decode_pages_projected_traced(
+            p, lo, plan, True, mesh=mesh))(pages)
+        with meshes.use_mesh(mesh):  # the standalone decodes read use_mesh
+            std = ops.decode_pages(pages, lo, use_kernel=True)
+        for got, want in zip(std, full):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         for got, want in zip(full, ref.decode_pages_ref(pages, lo)):
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         for got, want in zip(proj, ref.decode_pages_projected_ref(pages, lo, plan)):
