@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.engine import (
     Engine,
     batches_from_stream as _batches,
@@ -147,20 +148,21 @@ def train_units(
             "shard_model=True but the pre-built engine was made without it; "
             "pass make_engine(..., shard_model=True)"
         )
-    engine = engine or make_engine(
-        g, part, merge_coef=merge_coef, mesh=mesh, shard_model=shard_model,
-        use_fused_kernel=use_kernel is not False,
-    )
-    pool = pool or BufferPool(
-        pool_bytes=MAX_RESIDENT_PAGES * heap.layout.page_bytes,
-        page_bytes=heap.layout.page_bytes,
-    )
-    models = (
-        models
-        if models is not None
-        else init_models(g, np.random.default_rng(seed), scale=0.01)
-    )
-    models = [jnp.asarray(m) for m in models]
+    with obs.span("sql.plan"):
+        engine = engine or make_engine(
+            g, part, merge_coef=merge_coef, mesh=mesh, shard_model=shard_model,
+            use_fused_kernel=use_kernel is not False,
+        )
+        pool = pool or BufferPool(
+            pool_bytes=MAX_RESIDENT_PAGES * heap.layout.page_bytes,
+            page_bytes=heap.layout.page_bytes,
+        )
+        models = (
+            models
+            if models is not None
+            else init_models(g, np.random.default_rng(seed), scale=0.01)
+        )
+        models = [jnp.asarray(m) for m in models]
 
     epochs = max_epochs or g.epochs or 100
     coef = engine.merge_coef
@@ -189,11 +191,9 @@ def train_units(
                 exposed_epoch = decode_epoch = 0.0
                 gnorm_dev = None
                 for k, chunk_ids in enumerate(page_chunks):
-                    t0 = time.perf_counter()
-                    pages_np = handle.result()
-                    waited = time.perf_counter() - t0
+                    pages_np, waited, hidden = handle.wait()
                     exposed_epoch += waited
-                    overlapped_io_s += max(handle.fetch_s - waited, 0.0)
+                    overlapped_io_s += hidden
                     # enqueue the next fetch before dispatching compute;
                     # the epoch wrap primes chunk 0 for the next epoch —
                     # unless this is the last one (the convergence check
@@ -235,14 +235,9 @@ def train_units(
                         break
         finally:
             # drain the trailing (speculative) prefetch so the pool is
-            # quiescent on return; its outcome can't affect a result we
-            # already computed, so drain errors are suppressed — and a
-            # generator closed early (cancelled query) cleans up the same way
-            if not handle.cancel():
-                try:
-                    handle.result()
-                except Exception:
-                    pass
+            # quiescent on return — a generator closed early (cancelled
+            # query) cleans up the same way
+            handle.drain()
     return TrainResult(
         models=[np.asarray(m) for m in models],
         epochs_run=epochs_run,
@@ -257,6 +252,16 @@ def train_units(
         device_syncs=device_syncs,
         pipelined=True,
     )
+
+
+def run_units(gen):
+    """Drive a unit generator (``train_units``, a scan) to its end and
+    return what it returns."""
+    while True:
+        try:
+            next(gen)
+        except StopIteration as stop:
+            return stop.value
 
 
 def train(
@@ -288,17 +293,12 @@ def train(
     synchronous per-chunk loop (the ablation both tests and benchmarks
     compare against). ``use_kernel`` is as in ``train_units``."""
     if pipelined and heap.n_pages > 0:
-        gen = train_units(
+        return run_units(train_units(
             g, part, heap, pool=pool, mode=mode, engine=engine,
             max_epochs=max_epochs, merge_coef=merge_coef, models=models,
             seed=seed, mesh=mesh, shard_model=shard_model,
             use_kernel=use_kernel,
-        )
-        while True:
-            try:
-                next(gen)
-            except StopIteration as stop:
-                return stop.value
+        ))
 
     # -- synchronous executor (phases add; the ablation baseline) ------------
     t_start = time.perf_counter()

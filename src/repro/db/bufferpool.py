@@ -20,6 +20,7 @@ from concurrent.futures import Future, ThreadPoolExecutor
 
 import numpy as np
 
+from repro import obs
 from repro.db.heap import HeapFile
 
 
@@ -28,8 +29,9 @@ class PrefetchHandle:
 
     ``result()`` joins the fetch and returns the ``(n, page_words)`` uint32
     batch; ``fetch_s`` (valid once done) is the wall time the fetch itself
-    took, which callers compare against their blocked time to split I/O into
-    overlapped vs exposed seconds.
+    took. ``wait()`` is the scan loops' join: it splits the fetch's I/O into
+    what the caller blocked on (exposed) and what ran under its work
+    (overlapped); ``drain()`` leaves the pool quiescent when a loop ends.
     """
 
     def __init__(self, page_ids: np.ndarray):
@@ -46,6 +48,24 @@ class PrefetchHandle:
 
     def result(self, timeout: float | None = None) -> np.ndarray:
         return self._future.result(timeout)
+
+    def wait(self) -> tuple[np.ndarray, float, float]:
+        """Join the fetch in a ``pool.wait`` span: ``(pages, exposed_s,
+        overlapped_s)``, the seconds this call blocked and the seconds of
+        the fetch that ran before it."""
+        with obs.span("pool.wait", pages=len(self.page_ids)) as rec:
+            pages = self.result()
+        return pages, rec.seconds, max(self.fetch_s - rec.seconds, 0.0)
+
+    def drain(self) -> None:
+        """Cancel the fetch if it has not started, else wait for it. Its
+        outcome is dropped: the caller has its answer, or is failing
+        already."""
+        if not self.cancel():
+            try:
+                self.result()
+            except Exception:
+                pass
 
 
 class BufferPool:
@@ -88,8 +108,11 @@ class BufferPool:
                 if self._pins[key] <= 0:
                     del self._pins[key]
 
-    def fetch_batch(self, heap: HeapFile, page_ids: np.ndarray) -> np.ndarray:
-        """Batched page fetch -> (n, page_words) uint32, ready for the device.
+    def fetch_batch(self, heap: HeapFile, page_ids: np.ndarray,
+                    cause: obs.Span | None = None) -> np.ndarray:
+        """Batched page fetch -> (n, page_words) uint32, ready for the device,
+        in a ``pool.fetch`` span (``cause``: the span that asked for it from
+        another thread) counting pages, hits, misses and bytes handed out.
 
         Misses are read from disk in one pass; all requested pages end up
         resident (subject to capacity). The lock covers only hit/miss
@@ -101,24 +124,28 @@ class BufferPool:
         page_ids = np.asarray(page_ids)
         out = np.empty((len(page_ids), heap.layout.page_words), dtype=np.uint32)
         miss_pos, miss_ids = [], []
-        with self._lock:
-            for k, pid in enumerate(page_ids):
-                key = (heap.path, int(pid))
-                frame = self._frames.get(key)
-                if frame is not None:
-                    self.hits += 1
-                    self._frames.move_to_end(key)
-                    out[k] = frame
-                else:
-                    self.misses += 1
-                    miss_pos.append(k)
-                    miss_ids.append(int(pid))
-        if miss_ids:
-            fetched = heap.read_pages(np.array(miss_ids))
+        with obs.span("pool.fetch", cause=cause, pages=len(page_ids),
+                      bytes=out.nbytes) as rec:
             with self._lock:
-                for k, pid, frame in zip(miss_pos, miss_ids, fetched):
-                    out[k] = frame
-                    self._insert((heap.path, pid), frame.copy())
+                for k, pid in enumerate(page_ids):
+                    key = (heap.path, int(pid))
+                    frame = self._frames.get(key)
+                    if frame is not None:
+                        self.hits += 1
+                        self._frames.move_to_end(key)
+                        out[k] = frame
+                    else:
+                        self.misses += 1
+                        miss_pos.append(k)
+                        miss_ids.append(int(pid))
+            if miss_ids:
+                fetched = heap.read_pages(np.array(miss_ids))
+                with self._lock:
+                    for k, pid, frame in zip(miss_pos, miss_ids, fetched):
+                        out[k] = frame
+                        self._insert((heap.path, pid), frame.copy())
+            rec.misses = len(miss_ids)
+            rec.hits = len(page_ids) - rec.misses
         return out
 
     def prefetch_batch(self, heap: HeapFile, page_ids: np.ndarray) -> PrefetchHandle:
@@ -128,13 +155,14 @@ class BufferPool:
         fetch sequence would."""
         page_ids = np.asarray(page_ids)
         handle = PrefetchHandle(page_ids)
+        cause = obs.current()
 
         def work():
             if not handle._future.set_running_or_notify_cancel():
                 return
             try:
                 t0 = time.perf_counter()
-                pages = self.fetch_batch(heap, page_ids)
+                pages = self.fetch_batch(heap, page_ids, cause)
                 handle.fetch_s = time.perf_counter() - t0
                 handle._future.set_result(pages)
             except BaseException as e:  # surfaced to the caller at result()

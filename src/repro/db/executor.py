@@ -280,8 +280,6 @@ class QueryExecutor:
     def _predict_units(self, req: QueryRequest):
         """PredictScan under the double-buffered prefetch loop, yielding per
         chunk dispatch; ONE device sync per scan, then finalize."""
-        import jax
-
         from repro.db import scoring
 
         stmt = req.stmt
@@ -295,35 +293,11 @@ class QueryExecutor:
             else kw.get("into"),
             or_replace=stmt.or_replace or kw.get("or_replace", False),
         )
-        outs: list = []
-        exposed = overlapped = 0.0
-        t0 = time.perf_counter()
-        chunks = scan.page_chunks
-        if chunks:
-            handle = scan.pool.prefetch_batch(scan.heap, chunks[0])
-            try:
-                for k in range(len(chunks)):
-                    t_wait = time.perf_counter()
-                    pages_np = handle.result()
-                    waited = time.perf_counter() - t_wait
-                    exposed += waited
-                    overlapped += max(handle.fetch_s - waited, 0.0)
-                    if k + 1 < len(chunks):
-                        handle = scan.pool.prefetch_batch(
-                            scan.heap, chunks[k + 1]
-                        )
-                    outs.append(scan.run_chunk(pages_np))
-                    yield  # chunk dispatched — the scheduling point
-            finally:
-                # a closed generator (deadline cancel) must leave the pool
-                # quiescent, same contract as scoring._scan_chunks
-                if not handle.cancel():
-                    try:
-                        handle.result()
-                    except Exception:
-                        pass
-            jax.block_until_ready(outs)  # the scan's single sync
-        compute = time.perf_counter() - t0 - exposed
+        # a closed generator (deadline cancel) closes the scan's, which
+        # leaves the pool quiescent
+        outs, exposed, overlapped, compute = yield from scoring._scan_chunks(
+            scan.heap, scan.pool, scan.chunk, scan.run_chunk
+        )
         req.result = scan.finalize(outs, exposed, overlapped, compute, t_start)
 
     def _train_units(self, req: QueryRequest):
@@ -345,20 +319,12 @@ class QueryExecutor:
                 use_kernel=kw.get("use_kernel", self.use_kernel),
             )
         else:
-            gen = solver.train_units(
+            res = yield from solver.train_units(
                 artifact["hdfg"], artifact["partition"], heap,
                 pool=self.pool, mode=kw.get("mode", "dana"),
                 max_epochs=kw.get("max_epochs"), seed=kw.get("seed", 0),
                 use_kernel=kw.get("use_kernel", self.use_kernel),
             )
-            res = None
-            while res is None:
-                try:
-                    next(gen)
-                except StopIteration as stop:
-                    res = stop.value
-                    break
-                yield
         artifact["model"] = res.models
         self.catalog.register_udf(stmt.udf, artifact)
         req.result = q.QueryResult(

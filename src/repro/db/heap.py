@@ -6,6 +6,7 @@ import os
 
 import numpy as np
 
+from repro import obs
 from repro.db.page import PageLayout, build_pages
 
 
@@ -37,10 +38,12 @@ class HeapFile:
         return self.read_pages(np.array([page_id]))[0]
 
     def read_pages(self, page_ids: np.ndarray) -> np.ndarray:
-        """Returns (len(page_ids), page_words) uint32."""
+        """Returns (len(page_ids), page_words) uint32, read in a
+        ``heap.read`` span counting pages and bytes."""
         pw = self.layout.page_words
         out = np.empty((len(page_ids), pw), dtype=np.uint32)
-        with open(self.path, "rb") as f:
+        with (obs.span("heap.read", pages=len(page_ids), bytes=out.nbytes),
+              open(self.path, "rb") as f):
             for k, pid in enumerate(np.asarray(page_ids)):
                 f.seek(int(pid) * self.layout.page_bytes)
                 out[k] = np.frombuffer(f.read(self.layout.page_bytes), dtype=np.uint32)

@@ -51,6 +51,7 @@ import re
 
 import numpy as np
 
+from repro import obs
 from repro.core import solver
 from repro.db.bufferpool import BufferPool
 from repro.db.catalog import Catalog
@@ -594,53 +595,57 @@ def execute(
     (or ``or_replace=True``). A shared ``pool`` gives mixed train+score
     workloads one BufferPool.
     """
-    if isinstance(stmt, str):
-        stmt = parse(stmt)
-    if stmt.verb == "TRAIN":
-        artifact = catalog.udf(stmt.udf)
-        heap = HeapFile(catalog.table(stmt.table)["heap"])
-        res = solver.train(
-            artifact["hdfg"],
-            artifact["partition"],
-            heap,
-            pool=pool,
-            mode=mode,
-            max_epochs=max_epochs,
-            seed=seed,
-            pipelined=pipelined,
-            use_kernel=use_kernel,
-        )
-        artifact["model"] = res.models
-        catalog.register_udf(stmt.udf, artifact)
-        return QueryResult(
-            verb="TRAIN",
-            udf=stmt.udf,
-            table=stmt.table,
-            schema=("model",),
-            n_rows=heap.n_tuples,
-            rows_scanned=heap.n_tuples,
-            coefficients=res.models,
-            total_s=res.total_s,
-            exposed_io_s=res.exposed_io_s,
-            overlapped_io_s=res.overlapped_io_s,
-            compute_s=res.compute_s,
-            device_syncs=res.device_syncs,
-            train=res,
-        )
-    # PREDICT — lazy import: scoring pulls in kernels/serving only when used
-    from repro.db import scoring
+    with obs.span("sql.statement") as rec:
+        with obs.span("sql.plan"):
+            if isinstance(stmt, str):
+                stmt = parse(stmt)
+            if stmt.verb == "TRAIN":
+                artifact = catalog.udf(stmt.udf)
+                heap = HeapFile(catalog.table(stmt.table)["heap"])
+        rec.verb = stmt.verb
+        if stmt.verb == "TRAIN":
+            res = solver.train(
+                artifact["hdfg"],
+                artifact["partition"],
+                heap,
+                pool=pool,
+                mode=mode,
+                max_epochs=max_epochs,
+                seed=seed,
+                pipelined=pipelined,
+                use_kernel=use_kernel,
+            )
+            artifact["model"] = res.models
+            catalog.register_udf(stmt.udf, artifact)
+            return QueryResult(
+                verb="TRAIN",
+                udf=stmt.udf,
+                table=stmt.table,
+                schema=("model",),
+                n_rows=heap.n_tuples,
+                rows_scanned=heap.n_tuples,
+                coefficients=res.models,
+                total_s=res.total_s,
+                exposed_io_s=res.exposed_io_s,
+                overlapped_io_s=res.overlapped_io_s,
+                compute_s=res.compute_s,
+                device_syncs=res.device_syncs,
+                train=res,
+            )
+        # PREDICT — lazy import: scoring pulls in kernels/serving only when used
+        from repro.db import scoring
 
-    return scoring.execute_predict(
-        stmt,
-        catalog,
-        pool=pool,
-        use_kernel=use_kernel,
-        chunk_pages=chunk_pages,
-        max_new_tokens=max_new_tokens,
-        batch_slots=batch_slots,
-        into=stmt.insert_into if stmt.insert_into is not None else into,
-        or_replace=stmt.or_replace or or_replace,
-    )
+        return scoring.execute_predict(
+            stmt,
+            catalog,
+            pool=pool,
+            use_kernel=use_kernel,
+            chunk_pages=chunk_pages,
+            max_new_tokens=max_new_tokens,
+            batch_slots=batch_slots,
+            into=stmt.insert_into if stmt.insert_into is not None else into,
+            or_replace=stmt.or_replace or or_replace,
+        )
 
 
 def register_udf_from_trace(catalog: Catalog, name: str, fn, layout=None) -> dict:

@@ -61,7 +61,9 @@ import time
 
 import numpy as np
 
+from repro import obs
 from repro.core import striders
+from repro.core.solver import run_units
 from repro.db.bufferpool import BufferPool
 from repro.db.heap import HeapFile, write_table, write_token_table
 from repro.db.page import PageLayout
@@ -237,9 +239,11 @@ def _build_glm_chunk_fn(layout, plan, family, model, where, where_pos,
 
 
 def _scan_chunks(heap, pool, chunk_pages, run_chunk):
-    """Double-buffered page scan: fetch chunk k+1 on the pool's background
-    thread while the device runs chunk k; ONE host↔device join at the end.
-    Returns (chunk outputs, exposed_io_s, overlapped_io_s, compute_s)."""
+    """Double-buffered page scan as a unit generator: fetch chunk k+1 on the
+    pool's background thread while the device runs chunk k, yielding once
+    chunk k is dispatched; ONE host↔device join at the end. Returns (as
+    the generator's value) (chunk outputs, exposed_io_s, overlapped_io_s,
+    compute_s)."""
     import jax
 
     page_chunks = [
@@ -253,22 +257,17 @@ def _scan_chunks(heap, pool, chunk_pages, run_chunk):
         handle = pool.prefetch_batch(heap, page_chunks[0])
         try:
             for k in range(len(page_chunks)):
-                t_wait = time.perf_counter()
-                pages_np = handle.result()
-                waited = time.perf_counter() - t_wait
+                pages_np, waited, hidden = handle.wait()
                 exposed += waited
-                overlapped += max(handle.fetch_s - waited, 0.0)
+                overlapped += hidden
                 if k + 1 < len(page_chunks):
                     handle = pool.prefetch_batch(heap, page_chunks[k + 1])
                 outs.append(run_chunk(pages_np))
-        except BaseException:
+                yield  # chunk dispatched — the scheduling point
+        finally:
             # leave the pool quiescent even when a chunk blows up mid-scan
-            if not handle.cancel():
-                try:
-                    handle.result()
-                except Exception:
-                    pass
-            raise
+            # or the scan is closed early (a cancelled query)
+            handle.drain()
         jax.block_until_ready(outs)
     compute = time.perf_counter() - t0 - exposed
     return outs, exposed, overlapped, compute
@@ -307,100 +306,106 @@ class PredictScan:
 
     Two drivers share this: ``execute_predict`` runs the whole scan through
     the double-buffered ``_scan_chunks`` loop (one device sync), and the
-    concurrent executor (``db/executor.py``) steps ``page_chunks`` itself —
-    one chunk per scheduling unit — so PREDICT scans interleave with TRAIN
-    epochs over the shared pool without changing per-query results.
+    concurrent executor (``db/executor.py``) steps the same loop one chunk
+    per scheduling unit, so PREDICT scans interleave with TRAIN epochs over
+    the shared pool without changing per-query results.
     """
 
     def __init__(self, stmt, catalog, pool=None, *, use_kernel=None,
                  chunk_pages=None, into=None, or_replace=False):
-        self.stmt = stmt
-        self.catalog = catalog
-        self.into = into
-        self.or_replace = or_replace
-        self.artifact = catalog.udf(stmt.udf)
-        if self.artifact.get("kind") == "lm":
-            raise ValueError(
-                f"UDF {stmt.udf!r} is a language model; PredictScan covers "
-                f"GLM/LRMF scoring (the LM path runs a serving session)"
-            )
-        self.heap = HeapFile(catalog.table(stmt.table)["heap"])
-        layout = self.layout = self.heap.layout
-        self.chunk = chunk_pages or CHUNK_PAGES
-        self.pool = pool or BufferPool(
-            pool_bytes=self.chunk * layout.page_bytes,
-            page_bytes=layout.page_bytes,
-        )
-
-        family = self.family = _glm_family(self.artifact, stmt.udf)
-        model = self.model = _scoring_model(self.artifact, stmt.udf)
-        dm = model.shape[0]
-        if dm > layout.n_features:
-            raise ValueError(
-                f"UDF {stmt.udf!r} reads {dm} feature columns but table "
-                f"{stmt.table!r} has only {layout.n_features}"
-            )
-        if self.into is not None and stmt.aggregates is not None:
-            raise ValueError(
-                "aggregate queries reduce on device and never materialize "
-                "result pages; they cannot be INSERTed into a table"
+        with obs.span("sql.plan"):
+            self.stmt = stmt
+            self.catalog = catalog
+            self.into = into
+            self.or_replace = or_replace
+            self.artifact = catalog.udf(stmt.udf)
+            if self.artifact.get("kind") == "lm":
+                raise ValueError(
+                    f"UDF {stmt.udf!r} is a language model; PredictScan covers "
+                    f"GLM/LRMF scoring (the LM path runs a serving session)"
+                )
+            self.heap = HeapFile(catalog.table(stmt.table)["heap"])
+            layout = self.layout = self.heap.layout
+            self.chunk = chunk_pages or CHUNK_PAGES
+            self.pool = pool or BufferPool(
+                pool_bytes=self.chunk * layout.page_bytes,
+                page_bytes=layout.page_bytes,
             )
 
-        # ---- pushdown plan: model ∪ projection ∪ filter ∪ aggregate cols ---
-        if stmt.aggregates is not None:
-            proj_names: list[str] = []  # reductions project no row columns
-        elif stmt.columns is None:
-            proj_names = [f"c{i}" for i in range(layout.n_features)] + ["label"]
-        else:
-            proj_names = list(stmt.columns)
-        self.proj_names = proj_names
-        proj_idx = self.proj_idx = [
-            _column_index(n, layout) for n in proj_names
-        ]
-        include_label = None in proj_idx
-        decode_cols = set(range(dm)) | {i for i in proj_idx if i is not None}
-        where_map: dict[str, int | None] = {}
-        if stmt.where is not None:
-            for name in stmt.where.columns():
-                where_map[name] = _column_index(name, layout)
-            include_label = include_label or None in where_map.values()
-            decode_cols |= {i for i in where_map.values() if i is not None}
-        agg_map: dict[str, int | None] = {}
-        for a in stmt.aggregates or ():
-            if a.arg is None or a.arg == "prediction":
-                continue
-            agg_map[a.arg] = _column_index(a.arg, layout)
-            include_label = include_label or agg_map[a.arg] is None
-            if agg_map[a.arg] is not None:
-                decode_cols.add(agg_map[a.arg])
-        plan = self.plan = striders.projection_plan(
-            layout, decode_cols, include_label=bool(include_label)
-        )
-        self.pushdown = _pushdown_stats(self.heap, plan)
+            family = self.family = _glm_family(self.artifact, stmt.udf)
+            model = self.model = _scoring_model(self.artifact, stmt.udf)
+            dm = model.shape[0]
+            if dm > layout.n_features:
+                raise ValueError(
+                    f"UDF {stmt.udf!r} reads {dm} feature columns but table "
+                    f"{stmt.table!r} has only {layout.n_features}"
+                )
+            if self.into is not None and stmt.aggregates is not None:
+                raise ValueError(
+                    "aggregate queries reduce on device and never materialize "
+                    "result pages; they cannot be INSERTed into a table"
+                )
 
-        # plan positions (not table indices) for the traced tree/aggregates
-        where_pos = {
-            name: (None if idx is None else plan.columns.index(idx))
-            for name, idx in where_map.items()
-        }
-        agg_pos = {
-            name: plan.columns.index(idx)
-            for name, idx in agg_map.items() if idx is not None
-        }
-        self.run_chunk = _build_glm_chunk_fn(
-            layout, plan, family, model, stmt.where, where_pos, use_kernel,
-            [None if idx is None else plan.columns.index(idx)
-             for idx in proj_idx],
-            aggregates=stmt.aggregates, agg_pos=agg_pos,
-        )
-        self.page_chunks = [
-            np.arange(s, min(s + self.chunk, self.heap.n_pages))
-            for s in range(0, self.heap.n_pages, self.chunk)
-        ]
+            # ---- pushdown plan: model ∪ projection ∪ filter ∪ aggregate cols ---
+            if stmt.aggregates is not None:
+                proj_names: list[str] = []  # reductions project no row columns
+            elif stmt.columns is None:
+                proj_names = [f"c{i}" for i in range(layout.n_features)] + ["label"]
+            else:
+                proj_names = list(stmt.columns)
+            self.proj_names = proj_names
+            proj_idx = self.proj_idx = [
+                _column_index(n, layout) for n in proj_names
+            ]
+            include_label = None in proj_idx
+            decode_cols = set(range(dm)) | {i for i in proj_idx if i is not None}
+            where_map: dict[str, int | None] = {}
+            if stmt.where is not None:
+                for name in stmt.where.columns():
+                    where_map[name] = _column_index(name, layout)
+                include_label = include_label or None in where_map.values()
+                decode_cols |= {i for i in where_map.values() if i is not None}
+            agg_map: dict[str, int | None] = {}
+            for a in stmt.aggregates or ():
+                if a.arg is None or a.arg == "prediction":
+                    continue
+                agg_map[a.arg] = _column_index(a.arg, layout)
+                include_label = include_label or agg_map[a.arg] is None
+                if agg_map[a.arg] is not None:
+                    decode_cols.add(agg_map[a.arg])
+            plan = self.plan = striders.projection_plan(
+                layout, decode_cols, include_label=bool(include_label)
+            )
+            self.pushdown = _pushdown_stats(self.heap, plan)
+
+            # plan positions (not table indices) for the traced tree/aggregates
+            where_pos = {
+                name: (None if idx is None else plan.columns.index(idx))
+                for name, idx in where_map.items()
+            }
+            agg_pos = {
+                name: plan.columns.index(idx)
+                for name, idx in agg_map.items() if idx is not None
+            }
+            self.run_chunk = _build_glm_chunk_fn(
+                layout, plan, family, model, stmt.where, where_pos, use_kernel,
+                [None if idx is None else plan.columns.index(idx)
+                 for idx in proj_idx],
+                aggregates=stmt.aggregates, agg_pos=agg_pos,
+            )
 
     # -- finalization --------------------------------------------------------
     def finalize(self, outs, exposed, overlapped, compute, t_start):
-        """Collected chunk outputs (post-sync) -> QueryResult."""
+        """Collected chunk outputs (post-sync) -> QueryResult, in a
+        ``scan.finalize`` span counting the bytes of the outputs copied to
+        the host and the result's rows."""
+        nbytes = sum(x.nbytes for o in outs for x in o)
+        with obs.span("scan.finalize", bytes=nbytes) as rec:
+            res = self._result(outs, exposed, overlapped, compute, t_start)
+            rec.rows = res.n_rows
+        return res
+
+    def _result(self, outs, exposed, overlapped, compute, t_start):
         from repro.db import query as q
 
         stmt, heap = self.stmt, self.heap
@@ -532,9 +537,9 @@ def execute_predict(
         stmt, catalog, pool, use_kernel=use_kernel, chunk_pages=chunk_pages,
         into=into, or_replace=or_replace,
     )
-    outs, exposed, overlapped, compute = _scan_chunks(
+    outs, exposed, overlapped, compute = run_units(_scan_chunks(
         scan.heap, scan.pool, scan.chunk, scan.run_chunk
-    )
+    ))
     return scan.finalize(outs, exposed, overlapped, compute, t_start)
 
 
@@ -579,7 +584,8 @@ def _predict_lm(stmt, catalog, artifact, heap, pool, chunk, t_start, *,
             pages, layout, plan, use_kernel, mesh=current_mesh()
         )
 
-    outs, exposed, overlapped, compute = _scan_chunks(heap, pool, chunk, run)
+    outs, exposed, overlapped, compute = run_units(
+        _scan_chunks(heap, pool, chunk, run))
     if outs:
         feats = np.concatenate([np.asarray(o[0]) for o in outs])
         labels = np.concatenate([np.asarray(o[1]) for o in outs])

@@ -94,9 +94,8 @@ class Session:
         from repro.db import query as q
 
         self._check_open()
-        stmt = q.parse(text)
         return q.execute(
-            stmt, self._db.catalog, pool=self._db.pool,
+            text, self._db.catalog, pool=self._db.pool,
             into=into, or_replace=or_replace, **exec_kwargs,
         )
 

@@ -125,6 +125,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.dist import meshes
 from repro.models import model_zoo
 from repro.models.config import ModelConfig
@@ -954,16 +955,18 @@ class BatchedServer:
         the whole step, admission included; ``last_admit_s`` records the
         admission portion so the split stays assertable."""
         t0 = self._clock()
-        if self._faults is not None:
-            self._faults.apply(self, self._step_no)
-        if self._admit_stall > 0:
-            # admission stalled by a fault: deadlines still sweep (a stalled
-            # server must still shed load) but nothing enters a slot
-            self._admit_stall -= 1
-            self._sweep_deadlines(self._clock())
-        else:
-            self._admit()
-        self.last_admit_s = self._clock() - t0
+        with obs.span("serve.admit"):
+            if self._faults is not None:
+                self._faults.apply(self, self._step_no)
+            if self._admit_stall > 0:
+                # admission stalled by a fault: deadlines still sweep (a
+                # stalled server must still shed load) but nothing enters a
+                # slot
+                self._admit_stall -= 1
+                self._sweep_deadlines(self._clock())
+            else:
+                self._admit()
+            self.last_admit_s = self._clock() - t0
         if self.step_mode == "tokens":
             self._step_tokens(t0)
         else:
@@ -1047,35 +1050,36 @@ class BatchedServer:
 
     def _step_chunked(self, t0: float):
         """C uniform masked sub-steps across all slots (the reference)."""
-        # block allocation counts into wall time too: the paged-only host
-        # work (ensure_step + table upload) must count against paged wall
-        # time, or the CI-gated paged-vs-dense tok/s ratio flatters paged
-        if self._paged is not None:
-            # alloc-on-write: map blocks for the rows each slot writes this
-            # step (guaranteed to succeed when the pool is unfaulted —
-            # admission reserved the worst case; under injected shrinkage
-            # _ensure_or_preempt evicts to fit), COW-splitting any block
-            # still shared with another slot before the scatter lands
-            changed = False
-            cow_pairs: list[tuple[int, int]] = []
-            for i in range(self.slots):
-                if self.active[i] is None:
-                    continue
-                pos = int(self._positions[i])
-                n = min(self.prefill_chunk, self.max_seq - pos)
-                if n > 0:
-                    changed |= self._ensure_or_preempt(i, pos, n, cow_pairs)
-            if cow_pairs:
-                self._apply_cow(cow_pairs)
-            if changed or not self._tables_fresh:
-                tf, tr = self._paged.tables()
-                self._table_dev = jnp.asarray(tf)
-                self._ring_dev = (jnp.asarray(tr) if tr is not None
-                                  else self._no_table)
-                self._tables_fresh = True
-            self.metrics.kv_blocks_peak = max(
-                self.metrics.kv_blocks_peak, self._paged.pool.blocks_in_use
-            )
+        with obs.span("serve.prep"):
+            # block allocation counts into wall time too: the paged-only host
+            # work (ensure_step + table upload) must count against paged wall
+            # time, or the CI-gated paged-vs-dense tok/s ratio flatters paged
+            if self._paged is not None:
+                # alloc-on-write: map blocks for the rows each slot writes this
+                # step (guaranteed to succeed when the pool is unfaulted —
+                # admission reserved the worst case; under injected shrinkage
+                # _ensure_or_preempt evicts to fit), COW-splitting any block
+                # still shared with another slot before the scatter lands
+                changed = False
+                cow_pairs: list[tuple[int, int]] = []
+                for i in range(self.slots):
+                    if self.active[i] is None:
+                        continue
+                    pos = int(self._positions[i])
+                    n = min(self.prefill_chunk, self.max_seq - pos)
+                    if n > 0:
+                        changed |= self._ensure_or_preempt(i, pos, n, cow_pairs)
+                if cow_pairs:
+                    self._apply_cow(cow_pairs)
+                if changed or not self._tables_fresh:
+                    tf, tr = self._paged.tables()
+                    self._table_dev = jnp.asarray(tf)
+                    self._ring_dev = (jnp.asarray(tr) if tr is not None
+                                      else self._no_table)
+                    self._tables_fresh = True
+                self.metrics.kv_blocks_peak = max(
+                    self.metrics.kv_blocks_peak, self._paged.pool.blocks_in_use
+                )
         old_pos = self._positions.copy()
         ctx = (meshes.use_mesh(self.mesh) if self.mesh is not None
                else contextlib.nullcontext())
@@ -1094,53 +1098,54 @@ class BatchedServer:
         # _admit writes these in place on admission
         self._positions = np.array(positions)
         self._last_tok = np.array(last_tok)
-        now = self._clock()
+        with obs.span("serve.emit"):
+            now = self._clock()
 
-        n_active = 0
-        generated = 0
-        for i, req in enumerate(self.active):
-            if req is None:
-                continue
-            n_active += 1
-            req.steps += 1
-            plen = int(self._prompt_len[i])
-            # prefill vs decode token split: prompt tokens fed this step
-            # (chunked stepping feeds up to C), generations counted on emit
-            fed = (min(int(self._positions[i]), plen)
-                   - min(int(old_pos[i]), plen))
-            self.metrics.prompt_tokens += fed
-            ten = self.metrics.tenant(req.tenant)
-            ten["prompt_tokens"] += fed
-            emitted = 0
-            for j in range(toks.shape[0]):
-                # truncate at max_new: the device may over-generate up to
-                # C-1 tokens in the final chunk of a request
-                if not emits[j, i] or len(req.out) >= req.max_new_tokens:
+            n_active = 0
+            generated = 0
+            for i, req in enumerate(self.active):
+                if req is None:
                     continue
-                req.out.append(int(toks[j, i]))
-                emitted += 1
-                if req.ttft_s is None:
-                    self._record_first_token(req, now)
-            generated += emitted
-            ten["tokens_generated"] += emitted
-            # index the newly completed feed blocks BEFORE any finish-time
-            # release: freed blocks must never enter the index
-            self._register_prefix(i)
-            if (len(req.out) >= req.max_new_tokens
-                    or int(self._positions[i]) >= self.max_seq):
-                self._finish(req, i)
-        self.metrics.steps += 1
-        self.metrics.active_slot_steps += n_active
-        self.metrics.tokens_generated += generated
-        # chunked honesty: the fused program computes every slot row for all
-        # C sub-steps, live or not
-        self.metrics.batched_tokens += self.slots * self.prefill_chunk
-        # KV traffic: every advanced position scattered one row into each
-        # cache region (COW copy bytes were added by _apply_cow)
-        self.metrics.kv_bytes_written += (
-            int((self._positions - old_pos).sum()) * self._kv_row_bytes
-        )
-        self.metrics.wall_s += now - t0
+                n_active += 1
+                req.steps += 1
+                plen = int(self._prompt_len[i])
+                # prefill vs decode token split: prompt tokens fed this step
+                # (chunked stepping feeds up to C), generations counted on emit
+                fed = (min(int(self._positions[i]), plen)
+                       - min(int(old_pos[i]), plen))
+                self.metrics.prompt_tokens += fed
+                ten = self.metrics.tenant(req.tenant)
+                ten["prompt_tokens"] += fed
+                emitted = 0
+                for j in range(toks.shape[0]):
+                    # truncate at max_new: the device may over-generate up to
+                    # C-1 tokens in the final chunk of a request
+                    if not emits[j, i] or len(req.out) >= req.max_new_tokens:
+                        continue
+                    req.out.append(int(toks[j, i]))
+                    emitted += 1
+                    if req.ttft_s is None:
+                        self._record_first_token(req, now)
+                generated += emitted
+                ten["tokens_generated"] += emitted
+                # index the newly completed feed blocks BEFORE any finish-time
+                # release: freed blocks must never enter the index
+                self._register_prefix(i)
+                if (len(req.out) >= req.max_new_tokens
+                        or int(self._positions[i]) >= self.max_seq):
+                    self._finish(req, i)
+            self.metrics.steps += 1
+            self.metrics.active_slot_steps += n_active
+            self.metrics.tokens_generated += generated
+            # chunked honesty: the fused program computes every slot row for all
+            # C sub-steps, live or not
+            self.metrics.batched_tokens += self.slots * self.prefill_chunk
+            # KV traffic: every advanced position scattered one row into each
+            # cache region (COW copy bytes were added by _apply_cow)
+            self.metrics.kv_bytes_written += (
+                int((self._positions - old_pos).sum()) * self._kv_row_bytes
+            )
+            self.metrics.wall_s += now - t0
 
     def _step_tokens(self, t0: float):
         """One variable-composition token batch (vLLM-style): prefilling
@@ -1150,66 +1155,67 @@ class BatchedServer:
         scheduled row is the same one-token decode at the same position —
         with two differences that cannot change tokens: prompt-overshoot
         rows are never scheduled, and idle slots contribute no rows."""
-        chunk = self.prefill_chunk
-        work: list[tuple[int, int, int]] = []  # (slot, start_pos, n_rows)
-        for i, req in enumerate(self.active):
-            if req is None:
-                continue
-            p = int(self._positions[i])
-            plen = int(self._prompt_len[i])
-            n = min(chunk, plen - p) if p < plen else 1
-            n = min(n, self.max_seq - p)
-            work.append((i, p, n))
-        if self._paged is not None:
-            # map blocks BEFORE building the flat batch: under injected pool
-            # shrinkage _ensure_or_preempt may evict slots, and an evicted
-            # slot must not schedule rows this step. COW splits land here
-            # too — before the per-token tables are gathered
-            cow_pairs: list[tuple[int, int]] = []
+        with obs.span("serve.prep"):
+            chunk = self.prefill_chunk
+            work: list[tuple[int, int, int]] = []  # (slot, start_pos, n_rows)
+            for i, req in enumerate(self.active):
+                if req is None:
+                    continue
+                p = int(self._positions[i])
+                plen = int(self._prompt_len[i])
+                n = min(chunk, plen - p) if p < plen else 1
+                n = min(n, self.max_seq - p)
+                work.append((i, p, n))
+            if self._paged is not None:
+                # map blocks BEFORE building the flat batch: under injected pool
+                # shrinkage _ensure_or_preempt may evict slots, and an evicted
+                # slot must not schedule rows this step. COW splits land here
+                # too — before the per-token tables are gathered
+                cow_pairs: list[tuple[int, int]] = []
+                for i, p, n in work:
+                    if self.active[i] is not None:
+                        self._ensure_or_preempt(i, p, n, cow_pairs)
+                if cow_pairs:
+                    self._apply_cow(cow_pairs)
+                work = [(i, p, n) for i, p, n in work
+                        if self.active[i] is not None]
+            t_live = sum(n for _, _, n in work)
+            if t_live == 0:
+                # nothing runnable this step (empty batch); still a step
+                self.metrics.steps += 1
+                self.metrics.wall_s += self._clock() - t0
+                return
+            # pad the batch to an 8-token bucket: bounds the set of distinct
+            # shapes the jitted step compiles for; padding rows are dead (live
+            # False gates their writes, their samples are never read)
+            t_pad = max(8, -(-t_live // 8) * 8)
+            tokens = np.zeros(t_pad, np.int32)
+            slot_ids = np.zeros(t_pad, np.int32)
+            pos = np.zeros(t_pad, np.int32)
+            live = np.zeros(t_pad, bool)
+            last_row: dict[int, int] = {}
+            k = 0
             for i, p, n in work:
-                if self.active[i] is not None:
-                    self._ensure_or_preempt(i, p, n, cow_pairs)
-            if cow_pairs:
-                self._apply_cow(cow_pairs)
-            work = [(i, p, n) for i, p, n in work
-                    if self.active[i] is not None]
-        t_live = sum(n for _, _, n in work)
-        if t_live == 0:
-            # nothing runnable this step (empty batch); still a step
-            self.metrics.steps += 1
-            self.metrics.wall_s += self._clock() - t0
-            return
-        # pad the batch to an 8-token bucket: bounds the set of distinct
-        # shapes the jitted step compiles for; padding rows are dead (live
-        # False gates their writes, their samples are never read)
-        t_pad = max(8, -(-t_live // 8) * 8)
-        tokens = np.zeros(t_pad, np.int32)
-        slot_ids = np.zeros(t_pad, np.int32)
-        pos = np.zeros(t_pad, np.int32)
-        live = np.zeros(t_pad, bool)
-        last_row: dict[int, int] = {}
-        k = 0
-        for i, p, n in work:
-            plen = int(self._prompt_len[i])
-            if p < plen:
-                tokens[k:k + n] = self._prompt_buf[i, p:p + n]
+                plen = int(self._prompt_len[i])
+                if p < plen:
+                    tokens[k:k + n] = self._prompt_buf[i, p:p + n]
+                else:
+                    tokens[k] = self._last_tok[i]
+                slot_ids[k:k + n] = i
+                pos[k:k + n] = np.arange(p, p + n, dtype=np.int32)
+                live[k:k + n] = True
+                last_row[i] = k + n - 1
+                k += n
+            if self._paged is not None:
+                tf, tr = self._paged.token_tables(slot_ids)
+                table_dev = jnp.asarray(tf)
+                ring_dev = (jnp.asarray(tr) if tr is not None
+                            else self._no_table)
+                self.metrics.kv_blocks_peak = max(
+                    self.metrics.kv_blocks_peak, self._paged.pool.blocks_in_use
+                )
             else:
-                tokens[k] = self._last_tok[i]
-            slot_ids[k:k + n] = i
-            pos[k:k + n] = np.arange(p, p + n, dtype=np.int32)
-            live[k:k + n] = True
-            last_row[i] = k + n - 1
-            k += n
-        if self._paged is not None:
-            tf, tr = self._paged.token_tables(slot_ids)
-            table_dev = jnp.asarray(tf)
-            ring_dev = (jnp.asarray(tr) if tr is not None
-                        else self._no_table)
-            self.metrics.kv_blocks_peak = max(
-                self.metrics.kv_blocks_peak, self._paged.pool.blocks_in_use
-            )
-        else:
-            table_dev = ring_dev = self._no_table
+                table_dev = ring_dev = self._no_table
         ctx = (meshes.use_mesh(self.mesh) if self.mesh is not None
                else contextlib.nullcontext())
         with ctx:
@@ -1219,46 +1225,47 @@ class BatchedServer:
                 self.key, table_dev, ring_dev,
             )
         nxt = np.asarray(nxt)  # sync point: one per step
-        now = self._clock()
+        with obs.span("serve.emit"):
+            now = self._clock()
 
-        n_active = 0
-        generated = 0
-        for i, p, n in work:
-            req = self.active[i]
-            n_active += 1
-            req.steps += 1
-            plen = int(self._prompt_len[i])
-            new_p = p + n
-            self._positions[i] = new_p
-            fed = min(new_p, plen) - min(p, plen)
-            self.metrics.prompt_tokens += fed
-            ten = self.metrics.tenant(req.tenant)
-            ten["prompt_tokens"] += fed
-            if new_p >= plen:
-                # the slot's last scheduled row sits at the final prompt
-                # position or beyond: its sample is a real generation
-                tok = int(nxt[last_row[i]])
-                self._last_tok[i] = tok
-                if len(req.out) < req.max_new_tokens:
-                    req.out.append(tok)
-                    generated += 1
-                    ten["tokens_generated"] += 1
-                    if req.ttft_s is None:
-                        self._record_first_token(req, now)
-            # index the newly completed feed blocks BEFORE any finish-time
-            # release: freed blocks must never enter the index
-            self._register_prefix(i)
-            if (len(req.out) >= req.max_new_tokens
-                    or new_p >= self.max_seq):
-                self._finish(req, i)
-        self.metrics.steps += 1
-        self.metrics.active_slot_steps += n_active
-        self.metrics.tokens_generated += generated
-        self.metrics.batched_tokens += t_live
-        # KV traffic: every live row scattered once into each cache region
-        # (COW copy bytes were added by _apply_cow)
-        self.metrics.kv_bytes_written += t_live * self._kv_row_bytes
-        self.metrics.wall_s += now - t0
+            n_active = 0
+            generated = 0
+            for i, p, n in work:
+                req = self.active[i]
+                n_active += 1
+                req.steps += 1
+                plen = int(self._prompt_len[i])
+                new_p = p + n
+                self._positions[i] = new_p
+                fed = min(new_p, plen) - min(p, plen)
+                self.metrics.prompt_tokens += fed
+                ten = self.metrics.tenant(req.tenant)
+                ten["prompt_tokens"] += fed
+                if new_p >= plen:
+                    # the slot's last scheduled row sits at the final prompt
+                    # position or beyond: its sample is a real generation
+                    tok = int(nxt[last_row[i]])
+                    self._last_tok[i] = tok
+                    if len(req.out) < req.max_new_tokens:
+                        req.out.append(tok)
+                        generated += 1
+                        ten["tokens_generated"] += 1
+                        if req.ttft_s is None:
+                            self._record_first_token(req, now)
+                # index the newly completed feed blocks BEFORE any finish-time
+                # release: freed blocks must never enter the index
+                self._register_prefix(i)
+                if (len(req.out) >= req.max_new_tokens
+                        or new_p >= self.max_seq):
+                    self._finish(req, i)
+            self.metrics.steps += 1
+            self.metrics.active_slot_steps += n_active
+            self.metrics.tokens_generated += generated
+            self.metrics.batched_tokens += t_live
+            # KV traffic: every live row scattered once into each cache region
+            # (COW copy bytes were added by _apply_cow)
+            self.metrics.kv_bytes_written += t_live * self._kv_row_bytes
+            self.metrics.wall_s += now - t0
 
     def reset_metrics(self):
         kv_total = self.metrics.kv_blocks_total
