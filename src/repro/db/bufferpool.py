@@ -73,10 +73,17 @@ class BufferPool:
         """``pool_bytes`` is the pool's total frame budget in BYTES; capacity
         in pages is ``pool_bytes // page_bytes`` (floor, min 1 frame). The
         default is 8 MB = 256 frames of 32 KB pages. Callers sizing by page
-        count should pass ``pool_bytes=n_pages * page_bytes``."""
+        count should pass ``pool_bytes=n_pages * page_bytes``.
+
+        Frames are the rows of a ``capacity x page_words`` arena, one arena
+        a page size; ``_frames`` maps ``(path, page_id)`` to its row in LRU
+        order. Pages go in and out by copy, so nothing handed out aliases a
+        frame."""
         self.page_bytes = page_bytes
         self.capacity = max(1, pool_bytes // page_bytes)
-        self._frames: OrderedDict[tuple[str, int], np.ndarray] = OrderedDict()
+        self._frames: OrderedDict[tuple[str, int], int] = OrderedDict()
+        self._free = list(range(self.capacity))  # arena rows no frame holds
+        self._arenas: dict[int, np.ndarray] = {}
         self._pins: dict[tuple[str, int], int] = {}
         self.hits = 0
         self.misses = 0
@@ -86,19 +93,21 @@ class BufferPool:
 
     # -- core API ------------------------------------------------------------
     def get_page(self, heap: HeapFile, page_id: int, pin: bool = False) -> np.ndarray:
+        """A copy of the page, which stays valid after its frame goes."""
         with self._lock:
             key = (heap.path, page_id)
-            frame = self._frames.get(key)
-            if frame is not None:
+            slot = self._frames.get(key)
+            if slot is not None:
                 self.hits += 1
                 self._frames.move_to_end(key)
+                page = self._arena(heap)[slot].copy()
             else:
                 self.misses += 1
-                frame = heap.read_page(page_id)
-                self._insert(key, frame)
+                page = heap.read_page(page_id)
+                self._arena(heap)[self._insert(key)] = page
             if pin:
                 self._pins[key] = self._pins.get(key, 0) + 1
-            return frame
+            return page
 
     def unpin(self, heap: HeapFile, page_id: int) -> None:
         with self._lock:
@@ -114,38 +123,41 @@ class BufferPool:
         in a ``pool.fetch`` span (``cause``: the span that asked for it from
         another thread) counting pages, hits, misses and bytes handed out.
 
-        Misses are read from disk in one pass; all requested pages end up
-        resident (subject to capacity). The lock covers only hit/miss
-        classification and frame insertion — the disk read itself runs
-        unlocked, so a foreground fetch is never stalled behind a large
-        background prefetch's I/O (a racing fetch of the same page at worst
-        reads it twice — both reads return identical bytes and both count as
-        misses; frames stay consistent)."""
+        Hits are copied out of their frames under the lock. The misses are
+        read unlocked, straight into their rows of the batch, one read a run
+        of consecutive ids (``HeapFile.read_pages``), so a foreground fetch
+        is never stalled behind a large background prefetch's I/O (a racing
+        fetch of the same page at worst reads it twice — both reads return
+        identical bytes and both count as misses; frames stay consistent).
+        Then, under the lock again, the misses take frames in order, evicting
+        as they go, and the rows that end up resident are copied into their
+        frames at once."""
         page_ids = np.asarray(page_ids)
+        path = heap.path
         out = np.empty((len(page_ids), heap.layout.page_words), dtype=np.uint32)
-        miss_pos, miss_ids = [], []
+        hit_pos, hit_slots, miss_pos, miss_ids = [], [], [], []
         with obs.span("pool.fetch", cause=cause, pages=len(page_ids),
                       bytes=out.nbytes) as rec:
             with self._lock:
-                for k, pid in enumerate(page_ids):
-                    key = (heap.path, int(pid))
-                    frame = self._frames.get(key)
-                    if frame is not None:
-                        self.hits += 1
-                        self._frames.move_to_end(key)
-                        out[k] = frame
+                for k, pid in enumerate(page_ids.tolist()):
+                    slot = self._frames.get((path, pid))
+                    if slot is not None:
+                        self._frames.move_to_end((path, pid))
+                        hit_pos.append(k)
+                        hit_slots.append(slot)
                     else:
-                        self.misses += 1
                         miss_pos.append(k)
-                        miss_ids.append(int(pid))
+                        miss_ids.append(pid)
+                self.hits += len(hit_pos)
+                self.misses += len(miss_pos)
+                if hit_pos:
+                    out[hit_pos] = self._arena(heap)[hit_slots]
             if miss_ids:
-                fetched = heap.read_pages(np.array(miss_ids))
+                heap.read_pages(miss_ids, out=[out[k] for k in miss_pos])
                 with self._lock:
-                    for k, pid, frame in zip(miss_pos, miss_ids, fetched):
-                        out[k] = frame
-                        self._insert((heap.path, pid), frame.copy())
+                    self._install(heap, miss_pos, miss_ids, out)
             rec.misses = len(miss_ids)
-            rec.hits = len(page_ids) - rec.misses
+            rec.hits = len(hit_pos)
         return out
 
     def prefetch_batch(self, heap: HeapFile, page_ids: np.ndarray) -> PrefetchHandle:
@@ -184,6 +196,8 @@ class BufferPool:
         with self._lock:
             self._frames.clear()
             self._pins.clear()
+            self._arenas.clear()
+            self._free = list(range(self.capacity))
 
     @property
     def resident(self) -> int:
@@ -199,19 +213,40 @@ class BufferPool:
                     )
         return self._prefetcher
 
-    def _insert(self, key, frame) -> None:
-        if key in self._frames:  # same-key overwrite doesn't grow the pool
-            self._frames[key] = frame
+    def _arena(self, heap: HeapFile) -> np.ndarray:
+        pw = heap.layout.page_words
+        arena = self._arenas.get(pw)
+        if arena is None:
+            arena = self._arenas[pw] = np.empty((self.capacity, pw), np.uint32)
+        return arena
+
+    def _install(self, heap: HeapFile, positions, page_ids, batch) -> None:
+        """Make the pages of ``batch[positions]`` resident, in order, as one
+        insert each would, then fill their frames with one copy."""
+        slots = []
+        try:
+            for pid in page_ids:
+                slots.append(self._insert((heap.path, pid)))
+        finally:
+            # a frame taken twice (its page evicted by a later one, or a
+            # page asked twice) holds what took it last
+            last = dict(zip(slots, positions))
+            rows = list(last.values())
+            src = batch if rows == list(range(len(batch))) else batch[rows]
+            self._arena(heap)[list(last)] = src
+
+    def _insert(self, key) -> int:
+        """Make ``key`` the most recently used frame, evicting the least
+        recently used unpinned ones to make room; returns its arena row."""
+        slot = self._frames.get(key)
+        if slot is not None:  # same-key overwrite doesn't grow the pool
             self._frames.move_to_end(key)
-            return
+            return slot
         while len(self._frames) >= self.capacity:
-            evicted = False
-            for victim in self._frames:
-                if victim not in self._pins:
-                    del self._frames[victim]
-                    self.evictions += 1
-                    evicted = True
-                    break
-            if not evicted:
+            victim = next((k for k in self._frames if k not in self._pins), None)
+            if victim is None:
                 raise RuntimeError("buffer pool exhausted: all frames pinned")
-        self._frames[key] = frame
+            self._free.append(self._frames.pop(victim))
+            self.evictions += 1
+        slot = self._frames[key] = self._free.pop()
+        return slot

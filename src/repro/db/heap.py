@@ -11,6 +11,7 @@ from repro.db.page import PageLayout, build_pages
 
 
 FORMAT_VERSION = 2  # v2: MAXALIGN-unit line pointers, u32 tuple length
+IOV_MAX = 1024  # the most buffers one vectored read takes (Linux, macOS)
 
 
 class HeapFile:
@@ -37,17 +38,50 @@ class HeapFile:
     def read_page(self, page_id: int) -> np.ndarray:
         return self.read_pages(np.array([page_id]))[0]
 
-    def read_pages(self, page_ids: np.ndarray) -> np.ndarray:
-        """Returns (len(page_ids), page_words) uint32, read in a
-        ``heap.read`` span counting pages and bytes."""
-        pw = self.layout.page_words
-        out = np.empty((len(page_ids), pw), dtype=np.uint32)
-        with (obs.span("heap.read", pages=len(page_ids), bytes=out.nbytes),
-              open(self.path, "rb") as f):
-            for k, pid in enumerate(np.asarray(page_ids)):
-                f.seek(int(pid) * self.layout.page_bytes)
-                out[k] = np.frombuffer(f.read(self.layout.page_bytes), dtype=np.uint32)
+    def read_pages(self, page_ids: np.ndarray, out=None):
+        """Reads page ``page_ids[k]`` into ``out[k]`` and returns ``out``: an
+        (n, page_words) uint32 array (allocated when None) or a list of n
+        page rows, such as the rows of a larger batch.
+
+        Each maximal run of consecutive ids, in the order given, is one
+        ``os.preadv`` straight into its rows, split every ``IOV_MAX`` pages;
+        scattered ids cost a read each. The ``heap.read`` span counts pages,
+        bytes and ``reads``, the read calls issued."""
+        ids = np.asarray(page_ids, dtype=np.int64).reshape(-1)
+        if out is None:
+            out = np.empty((len(ids), self.layout.page_words), dtype=np.uint32)
+        pb = self.layout.page_bytes
+        breaks = (np.flatnonzero(np.diff(ids) != 1) + 1).tolist()
+        with obs.span("heap.read", pages=len(ids), bytes=len(ids) * pb,
+                      reads=0) as rec:
+            fd = os.open(self.path, os.O_RDONLY)
+            try:
+                for s, e in zip([0, *breaks], [*breaks, len(ids)]):
+                    for a in range(s, e, IOV_MAX):
+                        rec.reads += self._preadv(fd, out[a:min(a + IOV_MAX, e)],
+                                                  int(ids[a]) * pb)
+            finally:
+                os.close(fd)
         return out
+
+    def _preadv(self, fd: int, rows, offset: int) -> int:
+        """Fills ``rows`` from ``offset`` on, continuing after short reads;
+        returns the number of read calls."""
+        bufs = [memoryview(r).cast("B") for r in rows]
+        left = sum(len(b) for b in bufs)
+        calls = 0
+        while True:
+            got = os.preadv(fd, bufs, offset)
+            calls += 1
+            left -= got
+            if left == 0:
+                return calls
+            if got == 0:
+                raise EOFError(f"{self.path}: read past the end at byte {offset}")
+            offset += got
+            while got >= len(bufs[0]):  # drop the buffers filled
+                got -= len(bufs.pop(0))
+            bufs[0] = bufs[0][got:]
 
     def read_all(self) -> np.ndarray:
         data = np.fromfile(self.path, dtype=np.uint32)
