@@ -10,13 +10,16 @@ def p90(values):
 
 
 def ttft_ms(run):
-    """Each request due in the window: its due time to its first token, or
-    to the window's end when none came by then."""
+    """Each request due in the window: its due time to its first token, or,
+    when none came, to the time first tokens were awaited until: the
+    window's end, or past it where the mix waits for them
+    (``first_token_wait_s``)."""
+    until = getattr(run, "state", {}).get("ttft_until", run.window_t1)
     out = []
     for r in run.records:
         if run.window_t0 <= r["due"] < run.window_t1:
-            end = r["first"] if r["first"] is not None else run.window_t1
-            out.append(1e3 * (min(end, run.window_t1) - r["due"]))
+            end = r["first"] if r["first"] is not None else until
+            out.append(1e3 * (min(end, until) - r["due"]))
     return out
 
 

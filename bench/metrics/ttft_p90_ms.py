@@ -1,6 +1,8 @@
 """90th percentile time to first token over every request due in the
-window, from its due time (open loop); a request without a first token by
-the window's end counts with its age then (host clock)."""
+window, from its due time (open loop). Where the mix gives
+``first_token_wait_s``, each is followed past the window's close to its
+first token; one still without it when that wait ends, or at the close
+where the mix gives none, counts with its age then (host clock)."""
 from bench import latency
 
 

@@ -10,8 +10,11 @@ as the offered load keeps them; the ramp's last step ends by the window's
 start, where the window's first request is due. The window keeps submitting
 each request at its due time (between steps: a request due during a step
 waits for its end, and that wait counts in its latency) and stepping the
-server; it closes at the first step boundary after ``--seconds``. Every
-time is the benchmark's own clock, read when a step returns.
+server; it closes at the first step boundary after ``--seconds``. Where the
+mix gives ``first_token_wait_s``, the server then keeps stepping, with no
+new arrivals, until every request due in the window has its first token, at
+most that long: its time to first token is then its own, not its age at the
+close. Every time is the benchmark's own clock, read when a step returns.
 
 The check runs the plain reference (``bench/configs/<ref>.py``) over a
 sample of the finished requests, drawn from the seed, with the longest in
@@ -27,7 +30,10 @@ import numpy as np
 
 from bench import traffic
 
-# the program's ModelConfig field for each size the configuration states
+# the program's ModelConfig field for each size a configuration may state;
+# each is applied where the file states it. A file maps further keys itself
+# under ``program_fields`` (file key -> ModelConfig field): the count of
+# routed experts, which on a chip's share is the count held here, is one.
 WIDTHS = {
     "hidden_size": "d_model", "num_hidden_layers": "n_layers",
     "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
@@ -37,20 +43,49 @@ WIDTHS = {
     "qk_rope_head_dim": "qk_rope_head_dim", "v_head_dim": "v_head_dim",
     "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
     "tie_word_embeddings": "tie_embeddings",
+    "num_experts_per_tok": "experts_per_token",
+    "n_shared_experts": "n_shared_experts",
+    "moe_intermediate_size": "moe_d_ff",
+    "first_k_dense_replace": "first_dense_layers",
 }
+# groups of a configuration file that the harness reads, not the model
+HARNESS_KEYS = {"serving", "assumed", "departures", "not_applied", "published",
+                "program_fields"}
 SAMPLE_TOKENS = 512  # served tokens the check compares, at least
 SAMPLE_MAX = 8  # requests
 
 
 def _model_config(cfg: dict):
     """The program's model configuration for this file: the family and the
-    attention kind of the program's named configuration, every size from the
-    file, weights in the file's dtype."""
+    attention kind of the program's named configuration, every size the
+    file states, weights in the file's dtype. A number or a group (such as
+    ``rope_scaling``) the file states that reaches no field, and that
+    ``departures`` or ``not_applied`` does not give a reason for, is an
+    error: the program would run another model than the file states."""
     from repro.configs import get_config
 
+    base = get_config(cfg["program_config"])
+    extra = cfg.get("program_fields", {})
+    known = {f.name for f in dataclasses.fields(base)}
+    wrong = sorted(f"{k} -> {f}" for k, f in extra.items()
+                   if k not in cfg or f not in known)
+    if wrong:
+        raise ValueError(f"{cfg['name']}: program_fields maps a key the file "
+                         f"does not state, or to no ModelConfig field: {wrong}")
+    fields = {**WIDTHS, **extra}
+    declared = (set(cfg.get("departures", {})) | set(cfg.get("not_applied", {}))
+                | HARNESS_KEYS)
+    ignored = sorted(k for k, v in cfg.items()
+                     if isinstance(v, (int, float, dict)) and k not in fields
+                     and k not in declared)
+    if ignored:
+        raise ValueError(
+            f"{cfg['name']}: the file states {ignored}, which reach no field "
+            "of the program's ModelConfig: map each under program_fields, or "
+            "give the reason it is left out under not_applied")
     return dataclasses.replace(
-        get_config(cfg["program_config"]), param_dtype=cfg["torch_dtype"],
-        **{field: cfg[key] for key, field in WIDTHS.items()})
+        base, param_dtype=cfg["torch_dtype"],
+        **{f: cfg[k] for k, f in fields.items() if k in cfg})
 
 
 def make_weights(run, mcfg):
@@ -130,10 +165,11 @@ def setup(run) -> None:
 
 
 def _drive(run, until: float, tracer=None, trace_s: float = 0.0,
-           end_by: bool = False) -> None:
+           end_by: bool = False, stop=None) -> None:
     """Submit what is due and step the server until ``until`` has passed;
     with ``end_by``, start no step that would end after ``until`` and wait
-    for it instead (the ramp ends on the window's start)."""
+    for it instead (the ramp ends on the window's start); with ``stop``, also
+    end once ``stop()`` is true."""
     import jax
 
     from repro.serve.serving import Request
@@ -144,7 +180,7 @@ def _drive(run, until: float, tracer=None, trace_s: float = 0.0,
     step_s = 0.0
     while True:
         now = time.perf_counter()
-        if now >= until:
+        if now >= until or (stop is not None and stop()):
             break
         if end_by and now + step_s >= until:
             time.sleep(until - now)
@@ -211,6 +247,23 @@ def _queue(srv) -> tuple[int, int]:
     return len(srv.queue), sum(r is not None for r in srv.active)
 
 
+def _await_first_tokens(run, wait_s: float) -> None:
+    """After the window's close: submit what was due before it and step the
+    server until every request due in the window has its first token (or has
+    ended), or ``wait_s`` has passed; ``ttft_until`` is when that ended, the
+    time a request still without a first token counts its age to."""
+    t0, t1 = run.window_t0, run.window_t1
+
+    def served() -> bool:
+        return not any(p["due"] < t1 for p in run.state["pending"]) and all(
+            r["first"] is not None or r["done"] is not None
+            for r in run.records if t0 <= r["due"] < t1)
+
+    if wait_s > 0:
+        _drive(run, until=t1 + wait_s, stop=served)
+    run.state["ttft_until"] = time.perf_counter() if wait_s > 0 else t1
+
+
 def window(run, tracer) -> None:
     st = run.state
     n_steps0 = len(st["steps"])
@@ -222,6 +275,7 @@ def window(run, tracer) -> None:
     run.counters["queued_1"], run.counters["running_1"] = _queue(st["srv"])
     st["passes_1"] = {id(r): _passes(r, st["chunk"]) for r in run.records}
     steps = st["steps"][n_steps0:]
+    _await_first_tokens(run, float(run.traffic.get("first_token_wait_s", 0.0)))
     due = [r for r in run.records if run.window_t0 <= r["due"] < run.window_t1]
     run.attempted = len(due)
     run.failed = sum(1 for r in due if "error" in r

@@ -13,7 +13,11 @@ Two kinds of mix:
                 finished. ``rate_per_s`` is the offered rate; ``ramp_s`` of
                 arrivals run before the window. Prompt and output lengths are
                 lognormal (``median``, ``sigma``, clipped to ``[min, max]``);
-                prompt token ids are uniform over the vocabulary.
+                prompt token ids are uniform over the vocabulary. The
+                serving system reads ``trace_s`` (the traced part of the
+                window) and ``first_token_wait_s`` (how long past the
+                window's close its requests are followed to their first
+                token; see ``bench/systems/serving.py``).
 
 Every seed gets the same multiset of gaps and lengths, in another order: the
 gaps are the exponential distribution's quantiles at (i + 1/2)/n and the

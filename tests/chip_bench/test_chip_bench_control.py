@@ -14,6 +14,7 @@ CASES = {
     "tiny_logistic.train": "train_w_rel",
     "tiny_logistic.scan": "pred_abs",
     "tiny_mla.chat": "served_gap",
+    "tiny_mla.saturate": "served_gap",
 }
 
 

@@ -123,6 +123,44 @@ def test_latency_percentiles_and_open_loop_ttft():
     assert latency.tpot_ms(run) == pytest.approx([500.0, 1000.0])
 
 
+def test_ttft_follows_requests_past_the_close_where_the_mix_waits():
+    """With ``ttft_until`` set (the mix's ``first_token_wait_s``), a first
+    token after the window's close counts in full, and a request still
+    without one counts its age to ``ttft_until``, not to the close."""
+    from bench import latency
+
+    recs = [
+        {"due": 12.0, "first": 14.0},  # first token inside the window
+        {"due": 18.0, "first": 27.0},  # after the close: all 9 s count
+        {"due": 19.0, "first": None},  # none by the wait's end: age then
+        {"due": 21.0, "first": 22.0},  # due after the close: not counted
+    ]
+    run = types.SimpleNamespace(window_t0=10.0, window_t1=20.0, records=recs,
+                                state={"ttft_until": 30.0})
+    assert latency.ttft_ms(run) == pytest.approx([2000.0, 9000.0, 11000.0])
+    run.state = {}  # no wait: each is cut at the close, as before
+    assert latency.ttft_ms(run) == pytest.approx([2000.0, 2000.0, 1000.0])
+
+
+def test_saturate_tails_read_the_latency_arithmetic():
+    """Above the knee the tails are per-layer readings with the end-to-end
+    tails' arithmetic, and read nothing where no request has a reading."""
+    from bench import latency
+
+    recs = [{"due": 10.0 + i, "first": 10.5 + 1.5 * i, "done": 11.0 + 2 * i,
+             "last": 11.0 + 2 * i, "n_seen": 2 + i} for i in range(6)]
+    run = types.SimpleNamespace(window_t0=10.0, window_t1=20.0, records=recs)
+    ttft = load("metrics", "ttft_p90_ms.saturate")
+    tpot = load("metrics", "tpot_p90_ms.saturate")
+    assert ttft.read(run) == pytest.approx(latency.p90(latency.ttft_ms(run)))
+    assert tpot.read(run) == pytest.approx(latency.p90(latency.tpot_ms(run)))
+    # ttft 500, 1000, ..., 3000 ms: the exclusive p90 of six lies at rank
+    # 6.3, past the last by 0.3 of the last step
+    assert ttft.read(run) == pytest.approx(3150.0)
+    empty = types.SimpleNamespace(window_t0=10.0, window_t1=20.0, records=[])
+    assert ttft.read(empty) is None and tpot.read(empty) is None
+
+
 def test_open_loop_offers_every_seed_the_same_work():
     from bench import traffic
 

@@ -112,6 +112,8 @@ FAULTS = {
     "scan-column-swapped": ("tiny_logistic.scan", _column_swapped_scan),
     "chat-token-altered": ("tiny_mla.chat", _token_altered_chat),
     "chat-state-unchanged": ("tiny_mla.chat", _state_unchanged_chat),
+    "saturate-token-altered": ("tiny_mla.saturate", _token_altered_chat),
+    "saturate-state-unchanged": ("tiny_mla.saturate", _state_unchanged_chat),
 }
 
 

@@ -14,7 +14,8 @@ KEYS = ["correct", "attempted", "failed", "metrics", "device", "compared"]
 
 
 @pytest.mark.parametrize("workload", ["tiny_logistic.train",
-                                      "tiny_logistic.scan", "tiny_mla.chat"])
+                                      "tiny_logistic.scan", "tiny_mla.chat",
+                                      "tiny_mla.saturate"])
 def test_fixture_cell_runs_correct_with_the_result_schema(tiny_root, workload,
                                                           monkeypatch):
     from bench import harness
@@ -35,6 +36,40 @@ def test_fixture_cell_runs_correct_with_the_result_schema(tiny_root, workload,
     for c in res["compared"].values():
         assert set(c) == {"value", "limit"} and c["value"] <= c["limit"]
     json.loads(json.dumps(res))  # one JSON line
+
+
+@pytest.mark.parametrize("workload,waits", [("tiny_mla.chat", True),
+                                            ("tiny_mla.saturate", False)])
+def test_chat_window_requests_are_followed_to_their_first_token(
+        tiny_root, workload, waits, monkeypatch):
+    """A mix with ``first_token_wait_s`` steps the server past the window's
+    close until every request due in the window has its first token, and
+    counts no token of those steps in the window's; one without the key
+    stops at the close."""
+    from bench import harness
+
+    box = {}
+    make_run = harness.make_run
+
+    def keep(*a, **k):
+        box["run"] = make_run(*a, **k)
+        return box["run"]
+
+    monkeypatch.setattr(harness, "make_run", keep)
+    res = run_tiny(tiny_root, workload, monkeypatch)
+    run = box["run"]
+    due = [r for r in run.records
+           if run.window_t0 <= r["due"] < run.window_t1]
+    assert res["attempted"] == len(due) >= 1
+    after = run.state["ttft_until"] - run.window_t1
+    if waits:
+        assert all(r["first"] is not None for r in due)
+        assert 0 <= after < 10.0
+    else:
+        assert after == 0
+    window_steps = [t for t, _, _ in run.state["steps"]
+                    if run.window_t0 < t <= run.window_t1]
+    assert run.counters["steps"] == len(window_steps)
 
 
 def test_command_refuses_a_cpu_and_prints_no_result():
